@@ -32,6 +32,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import algorithms as A
 from repro.core.graph import EdgeDelta, Graph
 from repro.data.rmat import rmat_edges
@@ -231,11 +232,18 @@ def bench_sharded(scale: int, edge_factor: int, n_iter: int, repeats: int,
 
 
 def _sharded_leg(n_shards: int, args) -> dict:
-    """Run one sharded leg, in-process when the devices exist, else in a
-    subprocess that raises the simulated host device count first."""
+    """Run one sharded leg, in-process when the devices exist, else (on a
+    CPU host only) in a subprocess that raises the simulated host device
+    count first."""
     if len(jax.devices()) >= n_shards:
         return bench_sharded(args.bfs_scale, args.edge_factor, args.n_iter,
                              args.repeats, n_shards)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"sharded leg d={n_shards} needs {n_shards} devices but only "
+            f"{len(jax.devices())} {jax.default_backend()} device(s) are "
+            f"visible; a simulated CPU mesh would report CPU timings under "
+            f"this {jax.default_backend()} run")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_shards}")
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
@@ -254,6 +262,7 @@ def _sharded_leg(n_shards: int, args) -> dict:
 
 
 def main():
+    compile_cache.enable()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--scale", type=int, default=16,
                    help="log2 nodes for the native backend run")

@@ -159,6 +159,8 @@ def _spawn_leg(args, budget: int) -> dict:
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default="BENCH_memory.json")
     ap.add_argument("--scale", type=int, default=13)

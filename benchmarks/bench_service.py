@@ -47,7 +47,7 @@ import time
 import jax
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core.graph import Graph
 from repro.data.rmat import rmat_edges
 from repro.serve.graph_service import (GraphService, RejectedError, Workspace)
@@ -359,12 +359,48 @@ def _remote_client_loop(port: int, worker_id: int, queries: int,
 
 
 def _worker_main(args) -> int:
-    """Hidden subcommand: one client process of the multi-process phase."""
+    """Hidden subcommand: one client process of the remote phases."""
+    from repro.serve.client import pin_host_only
+    pin_host_only()          # load only: the server process owns the device
     out = _remote_client_loop(args.port, args.id, args.queries,
                               args.source_pool)
     with open(args.out, "w") as f:
         json.dump(out, f)
     return 0
+
+
+def _run_clients(port: int, ids, queries: int, source_pool: int) -> list:
+    """Run one client process per id against ``port``; their result dicts.
+
+    Clients are separate processes pinned to the host CPU, so the process
+    running this benchmark never decodes an array while the server holds
+    the device (a chip belongs to one process)."""
+    bench_path = os.path.abspath(__file__)
+    outs, procs = [], []
+    try:
+        for i in ids:
+            out_path = f"/tmp/bench_remote_worker_{os.getpid()}_{i}.json"
+            outs.append(out_path)
+            procs.append(subprocess.Popen(
+                [sys.executable, bench_path, "--_worker",
+                 "--port", str(port), "--id", str(i),
+                 "--queries", str(queries),
+                 "--source-pool", str(source_pool), "--out", out_path]))
+        for cp in procs:
+            rc = cp.wait(timeout=900)
+            assert rc == 0, f"remote client worker failed rc={rc}"
+        workers = []
+        for out_path in outs:
+            with open(out_path) as f:
+                workers.append(json.load(f))
+        return workers
+    finally:
+        for cp in procs:               # don't leave clients spinning
+            if cp.poll() is None:
+                cp.kill()
+        for out_path in outs:
+            if os.path.exists(out_path):
+                os.unlink(out_path)
 
 
 #: a wire hop costs a fixed few hundred microseconds of framing + syscalls;
@@ -379,9 +415,42 @@ REMOTE_OVERHEAD_FLOOR_MS = 1.0
 def run_remote(scale: int, edge_factor: int, clients: int,
                queries: int, source_pool: int) -> dict:
     """Remote serving vs in-process: cached-query overhead + multi-process
-    aggregate throughput against one spawned server."""
+    aggregate throughput against one spawned server.
+
+    The spawned server is the only process that touches the device while
+    it runs: every client is its own CPU-pinned process, and the in-process
+    baseline runs after the server has exited.  Call this before the
+    calling process has touched JAX on an accelerator.
+    """
     from repro.serve.client import RemoteService
     from repro.serve.server import spawn_server
+
+    # -- spawn the server (same RMAT seed -> same graph) -------------------
+    # generous startup deadline: on a contended single-core box the child's
+    # import + graph build can be starved for minutes without being wedged
+    proc, port = spawn_server(("--rmat-scale", str(scale),
+                               "--edge-factor", str(edge_factor),
+                               "--workers", "2"), timeout=300.0)
+    try:
+        # phase a: one remote client, solo -> clean wire-overhead number
+        remote_lat = _run_clients(port, [0], queries,
+                                  source_pool)[0]["latencies_ms"]
+
+        # phase b: N genuinely independent client processes
+        t0 = time.perf_counter()
+        workers = _run_clients(port, range(clients), queries, source_pool)
+        multi_wall = time.perf_counter() - t0
+        multi_lat = [x for w in workers for x in w["latencies_ms"]]
+
+        # ask the server to drain and exit; a clean rc is part of the bench
+        # (a bare socket client: this process stays off JAX meanwhile)
+        cli = RemoteService(port=port)
+        cli.shutdown_server()
+        cli.close()
+        server_rc = proc.wait(timeout=120)
+    except BaseException:
+        proc.kill()
+        raise
 
     # -- in-process baseline: same closed cached loop through the same
     # serving configuration (worker-dispatched service, submit -> result) --
@@ -400,58 +469,6 @@ def run_remote(scale: int, edge_factor: int, clients: int,
         p.result(timeout=600)
         inproc_lat.append(p.latency_ms)
     svc.close()
-
-    # -- spawn the server (same RMAT seed -> same graph) -------------------
-    # generous startup deadline: on a contended single-core box the child's
-    # import + graph build can be starved for minutes without being wedged
-    proc, port = spawn_server(("--rmat-scale", str(scale),
-                               "--edge-factor", str(edge_factor),
-                               "--workers", "2"), timeout=300.0)
-    outs = []
-    procs = []
-    try:
-        # phase a: one remote client, solo -> clean wire-overhead number
-        solo = _remote_client_loop(port, 0, queries, source_pool)
-        remote_lat = solo["latencies_ms"]
-
-        # phase b: N genuinely independent client processes
-        bench_path = os.path.abspath(__file__)
-        env = dict(os.environ)
-        for i in range(clients):
-            out_path = f"/tmp/bench_remote_worker_{os.getpid()}_{i}.json"
-            outs.append(out_path)
-            procs.append(subprocess.Popen(
-                [sys.executable, bench_path, "--_worker",
-                 "--port", str(port), "--id", str(i),
-                 "--queries", str(queries),
-                 "--source-pool", str(source_pool), "--out", out_path],
-                env=env))
-        t0 = time.perf_counter()
-        for cp in procs:
-            rc = cp.wait(timeout=900)
-            assert rc == 0, f"remote client worker failed rc={rc}"
-        multi_wall = time.perf_counter() - t0
-        workers = []
-        for out_path in outs:
-            with open(out_path) as f:
-                workers.append(json.load(f))
-            os.unlink(out_path)
-        multi_lat = [x for w in workers for x in w["latencies_ms"]]
-
-        # ask the server to drain and exit; a clean rc is part of the bench
-        cli = RemoteService(port=port)
-        cli.shutdown_server()
-        cli.close()
-        server_rc = proc.wait(timeout=120)
-    except BaseException:
-        proc.kill()
-        for cp in procs:               # don't leave clients spinning
-            if cp.poll() is None:
-                cp.kill()
-        for out_path in outs:
-            if os.path.exists(out_path):
-                os.unlink(out_path)
-        raise
 
     overhead = pctl(remote_lat, 50) / max(pctl(inproc_lat, 50),
                                           REMOTE_OVERHEAD_FLOOR_MS)
@@ -522,6 +539,13 @@ def main():
     p.add_argument("--skip-remote", action="store_true")
     p.add_argument("--out", default="BENCH_service.json")
     args = p.parse_args()
+    compile_cache.enable()
+
+    # the remote block runs first: its server child needs the device, so
+    # this process must not have touched JAX on it yet
+    remote = None if args.skip_remote else run_remote(
+        args.remote_scale, args.edge_factor, args.remote_clients,
+        args.remote_queries, args.remote_source_pool)
 
     src, dst = rmat_edges(args.scale, edge_factor=args.edge_factor, seed=0)
     g = Graph.from_edges(src, dst)
@@ -560,10 +584,8 @@ def main():
             args.overload_scale, args.edge_factor, args.overload_sessions,
             args.overload_queries, args.flood_quota)
 
-    if not args.skip_remote:
-        results["remote"] = run_remote(
-            args.remote_scale, args.edge_factor, args.remote_clients,
-            args.remote_queries, args.remote_source_pool)
+    if remote is not None:
+        results["remote"] = remote
 
     with open(args.out, "w") as f:
         json.dump(results, f, indent=2)

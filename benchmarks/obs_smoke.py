@@ -24,6 +24,9 @@ def main() -> int:
     t_start = time.perf_counter()
     import numpy as np
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     from repro import obs
     from repro.core import algorithms as A
     from repro.core.graph import Graph

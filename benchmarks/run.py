@@ -14,6 +14,8 @@ def main(argv=None) -> None:
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
     from . import paper_tables
     rows = paper_tables.run_all()
     print("name,us_per_call,derived")

@@ -18,6 +18,11 @@ import numpy as np
 
 def main() -> int:
     t_start = time.perf_counter()
+    from repro import compile_cache
+    from repro.serve.client import pin_host_only
+    compile_cache.enable()
+    # this process only generates load: the spawned server owns the device
+    pin_host_only()
     from repro.core import provenance as prov
     from repro.core.table import INT, Table
     from repro.serve.client import RemoteService

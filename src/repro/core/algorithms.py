@@ -114,7 +114,11 @@ def _pagerank_body(ex, pr, damping, inv_deg, dangling):
     n = ex.n_nodes
     summed = ex.pull(pr * inv_deg, "sum")        # rank mass along in-edges
     dang = jnp.sum(jnp.where(dangling, pr, 0.0))
-    return (1.0 - damping) / n + damping * (summed + dang / n)
+    # the uniform share is one scalar added after the scaled pull: written as
+    # ``summed + dang / n``, XLA folds the broadcast into the segment sum's
+    # initial value on the single-device path only, which changes the
+    # addition order and breaks bit-identity with the "sharded" backend
+    return damping * summed + ((1.0 - damping) + damping * dang) / n
 
 
 @track("algorithms.pagerank", "A.pagerank")
@@ -153,7 +157,8 @@ def pagerank(g: Graph, n_iter: int = 10, damping: float = 0.85, *,
 def _ppr_body(ex, pr, damping, inv_deg, dangling, restart):
     summed = ex.pull(pr * inv_deg, "sum")
     dang = jnp.sum(jnp.where(dangling, pr, 0.0))
-    return (1.0 - damping) * restart + damping * (summed + dang * restart)
+    # restart terms added after the scaled pull (see _pagerank_body)
+    return damping * summed + ((1.0 - damping) + damping * dang) * restart
 
 
 def _ppr_capped_body(ex, st, damping, inv_deg, dangling, restart, cap):
@@ -262,11 +267,12 @@ def triangle_count(g: Graph, edge_chunk: int = 1 << 16, *,
                          f"(mesh-partitioned); got {backend!r}")
     if g.n_edges == 0 or g.n_nodes == 0:
         return 0
+    plan = g.plan()
+    engine.select_backend(plan, backend or "xla")    # counts the choice
     if backend == "sharded":
         from ..launch.mesh import graph_mesh
         from .distributed import triangle_count_distributed
         return triangle_count_distributed(g, graph_mesh(engine.shard_count()))
-    plan = g.plan()
     if backend == "bsr":
         from ..kernels.bsr_tricount import bsr_tricount
         from ..kernels.ops import auto_interpret

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..launch.sharding import graph_replicated_spec, graph_shard_spec
 from .graph import Graph
@@ -153,7 +152,7 @@ def pagerank_distributed(dg: DistGraph, mesh: Mesh, n_iter: int = 10,
     n, ns = dg.n_nodes, dg.ns
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=P(axis),
     )
@@ -217,7 +216,7 @@ def distributed_to_graph(src: jax.Array, dst: jax.Array, n_nodes: int,
     dst_s = jax.device_put(dst, shard1)
     val_s = jax.device_put(valid, shard1)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis)),
                        out_specs=(P(axis), P(axis), P(axis)))
     def exchange(s, t, v):
@@ -238,9 +237,12 @@ def distributed_to_graph(src: jax.Array, dst: jax.Array, n_nodes: int,
         vb = jax.lax.all_to_all(vb, axis, split_axis=0, concat_axis=0, tiled=True)
         return sb.reshape(-1), tb.reshape(-1), vb.reshape(-1)
 
-    sb, tb, vb = exchange(src_s, dst_s, val_s)
+    # the graph mesh has explicit axes: eager shard_map calls on it need the
+    # mesh as ambient context (JAX >= 0.7 no longer infers it from inputs)
+    with jax.set_mesh(mesh):
+        sb, tb, vb = exchange(src_s, dst_s, val_s)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis)),
                        out_specs=(P(axis), P(axis), P(axis), P(axis)))
     def finalize(s, t, v):
@@ -257,7 +259,8 @@ def distributed_to_graph(src: jax.Array, dst: jax.Array, n_nodes: int,
         out_deg = jax.lax.dynamic_slice_in_dim(out_deg_full, me * ns, ns)
         return s, tl, v, out_deg.astype(jnp.float32)
 
-    s2, t2, v2, out_deg = finalize(sb, tb, vb)
+    with jax.set_mesh(mesh):
+        s2, t2, v2, out_deg = finalize(sb, tb, vb)
     es = d * cap
     nvalid = jax.device_put(
         (jnp.arange(d * ns) < n_nodes), shard1)
@@ -301,7 +304,7 @@ def triangle_count_distributed(g: Graph, mesh: Mesh, axis: str = "gp",
     evalid = jax.device_put(evalid, shard1)
     nbr_r = jax.device_put(nbr, graph_replicated_spec(mesh))  # replicated
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis), P()),
                        out_specs=P())
     def count(u, v, ev, nbr_l):
@@ -321,9 +324,7 @@ def triangle_count_distributed(g: Graph, mesh: Mesh, axis: str = "gp",
             return acc + jnp.sum(hit, dtype=jnp.int32)
 
         n_chunks = u.shape[0] // edge_chunk   # exact by construction
-        init = jnp.int32(0)                   # device-varying carry
-        if hasattr(jax.lax, "pvary"):         # required once jax >= 0.6
-            init = jax.lax.pvary(init, (axis,))
+        init = jax.lax.pcast(jnp.int32(0), (axis,), to="varying")
         total = jax.lax.fori_loop(0, n_chunks, chunk_body, init)
         return jax.lax.psum(total, axis)
 
@@ -334,13 +335,14 @@ def degrees_distributed(dg: DistGraph, mesh: Mesh, axis: str = "gp") -> jax.Arra
     """In-degrees from the sharded structure (sanity/benchmark helper)."""
     ns = dg.ns
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(axis), P(axis)),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(axis), P(axis)),
                        out_specs=P(axis))
     def run(dst_local, evalid):
         return jax.ops.segment_sum(evalid.astype(jnp.int32), dst_local,
                                    num_segments=ns, indices_are_sorted=True)
 
-    return run(dg.dst_local, dg.evalid)[: dg.n_nodes]
+    with jax.set_mesh(mesh):
+        return run(dg.dst_local, dg.evalid)[: dg.n_nodes]
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +442,7 @@ def pagerank_distributed_2d(dg: DistGraph2D, mesh: Mesh, n_iter: int = 10,
     nb_pad = slice_len * d  # pad block so it splits evenly into d slices
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P((row_axis, col_axis)), P((row_axis, col_axis)),
                   P((row_axis, col_axis)), P(col_axis)),
         out_specs=P((row_axis, col_axis)))

@@ -84,20 +84,19 @@ import functools
 import os
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from .. import obs
 from ..kernels.bsr_spmv import bsr_spmv
 from ..kernels.ops import auto_interpret
 from ..kernels.segment_sum import (DEFAULT_BLOCK, DEFAULT_CHUNK,
-                                   segment_sum_chunked)
+                                   chunk_values, segment_sum_chunked)
 from .table import next_capacity
 
 __all__ = ["BACKENDS", "select_backend", "get_exec", "push", "pull",
@@ -291,22 +290,20 @@ class PallasExec(XlaExec):
     and batched reductions fall back to the XLA primitives.
     """
 
-    p_chunk: jax.Array = None   # pull layout: (E,) chunk of edge
-    p_slot: jax.Array = None    # (E,) slot within chunk
+    p_src: jax.Array = None     # pull layout: (C, L) edge in each slot, pad E
     p_lids: jax.Array = None    # (C, L) local ids, pad = 128
     p_blk: jax.Array = None     # (C,) owning output block
-    q_chunk: jax.Array = None   # push layout (over out_src)
-    q_slot: jax.Array = None
+    q_src: jax.Array = None     # push layout (over out_src)
     q_lids: jax.Array = None
     q_blk: jax.Array = None
     nb_in: int = 0
     nb_out: int = 0
-    interpret: bool = True
+    interpret: bool = field(kw_only=True)   # stated by every construction
 
     def tree_flatten(self):
         return ((self.in_src, self.in_dst, self.out_src, self.out_dst,
-                 self.p_chunk, self.p_slot, self.p_lids, self.p_blk,
-                 self.q_chunk, self.q_slot, self.q_lids, self.q_blk),
+                 self.p_src, self.p_lids, self.p_blk,
+                 self.q_src, self.q_lids, self.q_blk),
                 (self.n_nodes, self.n_edges, self.nb_in, self.nb_out,
                  self.interpret))
 
@@ -316,11 +313,8 @@ class PallasExec(XlaExec):
         return cls(n_nodes, n_edges, *leaves, nb_in=nb_in, nb_out=nb_out,
                    interpret=interpret)
 
-    def _chunked_sum(self, edge_vals, chunk_of, slot_of, lids, blk, nb):
-        c, l = lids.shape
-        cvals = jnp.zeros((c, l), jnp.float32)
-        cvals = cvals.at[chunk_of, slot_of].set(edge_vals.astype(jnp.float32))
-        out = segment_sum_chunked(cvals, lids, blk, nb,
+    def _chunked_sum(self, edge_vals, src, lids, blk, nb):
+        out = segment_sum_chunked(chunk_values(edge_vals, src), lids, blk, nb,
                                   interpret=self.interpret)
         return out.reshape(-1)[: self.n_nodes]
 
@@ -330,15 +324,15 @@ class PallasExec(XlaExec):
         if (combine != "sum" or edge_vals.ndim != 1
                 or not jnp.issubdtype(edge_vals.dtype, jnp.floating)):
             return super().reduce_in(edge_vals, combine)
-        return self._chunked_sum(edge_vals, self.p_chunk, self.p_slot,
-                                 self.p_lids, self.p_blk, self.nb_in)
+        return self._chunked_sum(edge_vals, self.p_src, self.p_lids,
+                                 self.p_blk, self.nb_in)
 
     def reduce_out(self, edge_vals, combine="sum"):
         if (combine != "sum" or edge_vals.ndim != 1
                 or not jnp.issubdtype(edge_vals.dtype, jnp.floating)):
             return super().reduce_out(edge_vals, combine)
-        return self._chunked_sum(edge_vals, self.q_chunk, self.q_slot,
-                                 self.q_lids, self.q_blk, self.nb_out)
+        return self._chunked_sum(edge_vals, self.q_src, self.q_lids,
+                                 self.q_blk, self.nb_out)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -361,7 +355,7 @@ class BsrExec(XlaExec):
     cols_t: jax.Array = None
     nb: int = 0
     block: int = DEFAULT_BLOCK
-    interpret: bool = True
+    interpret: bool = field(kw_only=True)   # stated by every construction
 
     def tree_flatten(self):
         return ((self.in_src, self.in_dst, self.out_src, self.out_dst,
@@ -500,15 +494,15 @@ class ShardedExec(XlaExec):
 
         Inputs and outputs are replicated (``P()``); ``fn`` slices its own
         shard out of each flat ``(d * per_shard,)`` array via
-        ``axis_index``.  ``check_rep=False`` because the final
+        ``axis_index``.  ``check_vma=False`` because the final
         ``all_gather`` makes the output replicated by construction, which
-        jax's replication checker cannot infer.
+        jax's varying-manual-axes checker cannot infer.
         """
         if getattr(_MANUAL_REGION, "active", False):
             return fn(*args)
-        return shard_map(fn, mesh=self.mesh,
-                         in_specs=(PartitionSpec(),) * len(args),
-                         out_specs=PartitionSpec(), check_rep=False)(*args)
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=(PartitionSpec(),) * len(args),
+                             out_specs=PartitionSpec(), check_vma=False)(*args)
 
     def run_loop(self, loop, *args):
         """Run a whole fixpoint loop as one shard_map manual region.
@@ -527,16 +521,17 @@ class ShardedExec(XlaExec):
             finally:
                 _MANUAL_REGION.active = False
 
-        return shard_map(fn, mesh=self.mesh,
-                         in_specs=(PartitionSpec(), PartitionSpec()),
-                         out_specs=PartitionSpec(),
-                         check_rep=False)(self, args)
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=(PartitionSpec(), PartitionSpec()),
+                             out_specs=PartitionSpec(),
+                             check_vma=False)(self, args)
 
     def _rekey(self, edge_vals: jax.Array, slot: jax.Array,
                es: int) -> jax.Array:
         """Scatter global-edge-order values into the flat padded layout."""
-        return jnp.zeros((self.d * es,), edge_vals.dtype).at[slot] \
-            .set(edge_vals)
+        # slots ascend with global edge order (shard-major, then in order)
+        return jnp.zeros((self.d * es,), edge_vals.dtype).at[slot].set(
+            edge_vals, indices_are_sorted=True, unique_indices=True)
 
     def _exchange_reduce(self, x, combine, gidx, seg, bnd, es, halo,
                          ev_sh, edge_op):
@@ -672,10 +667,9 @@ def get_exec(plan, backend: Optional[str] = None, *,
         ptr, idx, deg_pad = plan.csr_out()
         ex = FrontierExec(*base, ptr, idx, deg_pad, plan.in_perm_out())
     elif backend == "pallas":
-        p_chunk, p_slot, p_lids, p_blk, nb_in, _ = plan.chunk_layout_in(chunk)
-        q_chunk, q_slot, q_lids, q_blk, nb_out, _ = plan.chunk_layout_out(chunk)
-        ex = PallasExec(*base, p_chunk, p_slot, p_lids, p_blk,
-                        q_chunk, q_slot, q_lids, q_blk,
+        p_src, p_lids, p_blk, nb_in, _ = plan.chunk_layout_in(chunk)
+        q_src, q_lids, q_blk, nb_out, _ = plan.chunk_layout_out(chunk)
+        ex = PallasExec(*base, p_src, p_lids, p_blk, q_src, q_lids, q_blk,
                         nb_in=nb_in, nb_out=nb_out, interpret=interp)
     else:
         tiles, rows, cols, nb = plan.bsr(block)
@@ -906,6 +900,26 @@ _DENSE_EDGE_DIV = 4
 _MIN_BUCKET = 16
 
 
+_SCAN_ROW = 1024
+
+
+def _int_cumsum(x):
+    """Inclusive prefix sum of a 1-D integer array, in rows of 1024.
+
+    Equal to ``jnp.cumsum`` (integer addition is associative), but a TPU
+    compiles one scan over millions of elements for about half a minute,
+    and short row scans plus a scan of the row totals in about a second.
+    """
+    m = x.shape[0]
+    if m <= _SCAN_ROW:
+        return jnp.cumsum(x)
+    r = -(-m // _SCAN_ROW)
+    rows = jnp.cumsum(jnp.pad(x, (0, r * _SCAN_ROW - m)).reshape(r, _SCAN_ROW),
+                      axis=1)
+    last = rows[:, -1]
+    return (rows + (_int_cumsum(last) - last)[:, None]).reshape(-1)[:m]
+
+
 def _stats_of(mask, deg):
     """(frontier size, frontier out-edge count) — the host's planning pair."""
     return jnp.stack([jnp.sum(mask.astype(jnp.int32)),
@@ -939,7 +953,7 @@ def _frontier_push_step(ex, state, f_idx, w_out, caps, t, *, e_budget):
     n = ex.n_nodes
     deg = ex.deg_pad[f_idx]
     off = ex.out_ptr[f_idx]
-    cum = jnp.cumsum(deg) - deg                           # exclusive prefix
+    cum = _int_cumsum(deg) - deg                          # exclusive prefix
     total = jnp.sum(deg)
     j = jnp.arange(e_budget, dtype=deg.dtype)
     owner = jnp.clip(jnp.searchsorted(cum, j, side="right") - 1,
@@ -981,6 +995,20 @@ def _frontier_dense_step(ex, state, w_in, caps, t):
 
 _frontier_stats = jax.jit(_stats_of)   # round-0 entry; later rounds get
                                        # stats fused into their step
+
+
+@functools.partial(jax.jit, static_argnames=("b",))
+def _compact(mask, *, b):
+    """First ``b`` set positions of ``mask`` in order, padded with ``n``.
+
+    ``nonzero(mask, size=b, fill_value=n)`` as a search of the running
+    count: the k-th set position is the first index whose prefix count
+    reaches k.  Same values, but it compiles in about a second at millions
+    of vertices, where ``nonzero``'s scatter takes half a minute per bucket.
+    """
+    count = _int_cumsum(mask.astype(jnp.int32))
+    return jnp.searchsorted(count, jnp.arange(1, b + 1, dtype=jnp.int32),
+                            side="left").astype(jnp.int32)
 
 
 def frontier_fixpoint(plan_or_exec, init, frontier, *,
@@ -1070,8 +1098,7 @@ def frontier_fixpoint(plan_or_exec, init, frontier, *,
             else:
                 b = min(next_capacity(cnt, minimum=_MIN_BUCKET),
                         next_capacity(max(n, 1)))
-                f_idx = jnp.nonzero(mask, size=b,
-                                    fill_value=n)[0].astype(jnp.int32)
+                f_idx = _compact(mask, b=b)
                 eb = next_capacity(max(fe, 1), minimum=_MIN_BUCKET)
                 shape_sig = (k, b, eb, w_out is None, str(state.dtype))
                 if shape_sig not in _TRACED_SHAPES:

@@ -151,30 +151,34 @@ class Graph:
 
     # -- constructors ----------------------------------------------------------
     @classmethod
-    def from_dense_edges(cls, src: jax.Array, dst: jax.Array, n_nodes: int,
-                         node_ids: Optional[jax.Array] = None) -> "Graph":
+    def from_dense_edges(cls, src, dst, n_nodes: int,
+                         node_ids=None) -> "Graph":
         """Build from dense-id edge arrays (valid length = full length).
 
         This is the core of the paper's **sort-first** algorithm (§2.4):
         (1) copy the columns, (2) sort them, (3) compute neighbor counts
         explicitly, (4) bulk-write adjacency — no contention, no estimates.
+        The sorts run on the host: a device sort compiles anew for every
+        edge count, and at tens of millions of edges that compile takes
+        longer than the host sort it would replace.  The CSR arrays are
+        uploaded once, built.
         """
-        src = src.astype(jnp.int32)
-        dst = dst.astype(jnp.int32)
+        src = np.asarray(src, dtype=np.int32).reshape(-1)
+        dst = np.asarray(dst, dtype=np.int32).reshape(-1)
         e = int(src.shape[0])
         node_cap = next_capacity(max(n_nodes, 1))
         edge_cap = next_capacity(max(e, 1))
 
-        if node_ids is None:
-            ids = jnp.where(jnp.arange(node_cap) < n_nodes,
-                            jnp.arange(node_cap, dtype=jnp.int32), INVALID_ID)
-        else:
-            ids = _pad_ids(node_ids, node_cap)
+        ids = np.full((node_cap,), INVALID_ID, np.int32)
+        ids[:n_nodes] = (np.arange(n_nodes, dtype=np.int32)
+                         if node_ids is None
+                         else np.asarray(node_ids, np.int32)[:n_nodes])
 
-        out_ptr, out_idx = _csr_from_pairs(src, dst, n_nodes, node_cap, edge_cap)
-        in_ptr, in_idx = _csr_from_pairs(dst, src, n_nodes, node_cap, edge_cap)
-        return cls(n_nodes=n_nodes, n_edges=e, node_ids=ids,
-                   out_ptr=out_ptr, out_idx=out_idx, in_ptr=in_ptr, in_idx=in_idx)
+        out_ptr, out_idx = _csr_from_pairs(src, dst, node_cap, edge_cap)
+        in_ptr, in_idx = _csr_from_pairs(dst, src, node_cap, edge_cap)
+        return cls(n_nodes=n_nodes, n_edges=e, node_ids=jnp.asarray(ids),
+                   out_ptr=jnp.asarray(out_ptr), out_idx=jnp.asarray(out_idx),
+                   in_ptr=jnp.asarray(in_ptr), in_idx=jnp.asarray(in_idx))
 
     @classmethod
     def from_edges(cls, src, dst, dedupe: bool = True,
@@ -184,31 +188,19 @@ class Graph:
         Node set = union of endpoint ids (paper §2.4: "Nodes V are defined by
         unique values in columns S and D").
         """
-        src = jnp.asarray(src, dtype=jnp.int32)
-        dst = jnp.asarray(dst, dtype=jnp.int32)
+        src = np.asarray(src, dtype=np.int32).reshape(-1)
+        dst = np.asarray(dst, dtype=np.int32).reshape(-1)
         if drop_self_loops:
             keep = src != dst
-            n_keep = int(jnp.sum(keep))
-            perm = jnp.argsort(~keep, stable=True)[:max(n_keep, 1)]
-            src, dst = src[perm][:n_keep], dst[perm][:n_keep]
-
-        # dense renumbering: the sort-based dual of Ringo's node hash table
-        all_ids = jnp.sort(jnp.concatenate([src, dst]))
-        if all_ids.shape[0] == 0:
+            src, dst = src[keep], dst[keep]
+        if src.shape[0] == 0:
             return cls.from_dense_edges(src, dst, 0)
-        firsts = jnp.concatenate([jnp.ones((1,), bool), all_ids[1:] != all_ids[:-1]])
-        n_nodes = int(jnp.sum(firsts))
-        node_cap = next_capacity(max(n_nodes, 1))
-        uniq_pos = jnp.nonzero(firsts, size=node_cap, fill_value=all_ids.shape[0] - 1)[0]
-        node_ids = jnp.where(jnp.arange(node_cap) < n_nodes, all_ids[uniq_pos],
-                             INVALID_ID)
-        valid_ids = node_ids[:n_nodes]
-        src_d = jnp.searchsorted(valid_ids, src).astype(jnp.int32)
-        dst_d = jnp.searchsorted(valid_ids, dst).astype(jnp.int32)
 
+        node_ids, src_d, dst_d = _renumber(src, dst)
         if dedupe:
-            src_d, dst_d = _dedupe_pairs(src_d, dst_d, n_nodes)
-        return cls.from_dense_edges(src_d, dst_d, n_nodes, node_ids=node_ids)
+            src_d, dst_d = _dedupe_pairs(src_d, dst_d)
+        return cls.from_dense_edges(src_d, dst_d, int(node_ids.size),
+                                    node_ids=node_ids)
 
     # -- accessors ---------------------------------------------------------------
     @property
@@ -542,25 +534,19 @@ def _host_ptr(rows: np.ndarray, node_cap: int) -> np.ndarray:
     return ptr.astype(np.int32)
 
 
-def _pad_ids(ids: jax.Array, cap: int) -> jax.Array:
-    n = int(ids.shape[0])
-    if n == cap:
-        return ids.astype(jnp.int32)
-    pad = jnp.full((cap - n,), INVALID_ID, dtype=jnp.int32)
-    return jnp.concatenate([ids.astype(jnp.int32), pad])
-
-
-def _csr_from_pairs(row: jax.Array, col: jax.Array, n_nodes: int,
-                    node_cap: int, edge_cap: int) -> Tuple[jax.Array, jax.Array]:
-    """Sort-first CSR: lexsort (row, col) -> counts -> ptr; no hash inserts."""
+def _csr_from_pairs(row: np.ndarray, col: np.ndarray, node_cap: int,
+                    edge_cap: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Sort-first CSR: sort (row, col) -> counts -> ptr; no hash inserts."""
     e = int(row.shape[0])
-    perm = jnp.lexsort((col, row))  # row primary, col secondary => sorted adjacency
-    col_sorted = col[perm]
-    counts = jnp.bincount(row, length=node_cap)  # "compute counts explicitly"
-    ptr = jnp.concatenate([jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])
-    idx = jnp.full((edge_cap,), jnp.int32(0))
-    idx = idx.at[:e].set(col_sorted.astype(jnp.int32)) if e > 0 else idx
-    return ptr.astype(jnp.int32), idx
+    # one sort of 64-bit (row, col) keys: row primary, col secondary =>
+    # sorted adjacency (dense ids are non-negative int32); deduped input
+    # arrives sorted already, and checking is linear
+    keys = (row.astype(np.int64) << 32) | col.astype(np.int64)
+    if not np.all(keys[1:] >= keys[:-1]):
+        keys.sort()
+    idx = np.zeros((edge_cap,), np.int32)
+    idx[:e] = (keys & 0xFFFFFFFF).astype(np.int32)
+    return _host_ptr((keys >> 32).astype(np.int64), node_cap), idx
 
 
 def _row_of_edge(ptr: jax.Array, edge_cap: int) -> jax.Array:
@@ -569,20 +555,34 @@ def _row_of_edge(ptr: jax.Array, edge_cap: int) -> jax.Array:
     return (jnp.searchsorted(ptr, e_idx, side="right") - 1).astype(jnp.int32)
 
 
-def _dedupe_pairs(src: jax.Array, dst: jax.Array, n_nodes: int
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """Remove duplicate (src, dst) pairs — lexsorted-unique, eager size.
+def _renumber(src: np.ndarray, dst: np.ndarray
+              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(node_ids, src_d, dst_d): ascending unique ids and dense endpoints.
 
-    Pure 32-bit: no combined key is formed, the pair is compared
-    componentwise after a lexsort (collision-free at any scale).
+    The sort-based dual of Ringo's node hash table.  When the ids are
+    non-negative and at most a few times the endpoint count, a presence
+    table and an id -> dense lookup replace the sort and the binary
+    searches (linear, and tens of times faster at millions of edges).
     """
+    lo = min(int(src.min()), int(dst.min()))
+    hi = max(int(src.max()), int(dst.max()))
+    if lo < 0 or hi >= 4 * (src.size + dst.size) + 1024:
+        node_ids = np.unique(np.concatenate([src, dst]))
+        return (node_ids, np.searchsorted(node_ids, src).astype(np.int32),
+                np.searchsorted(node_ids, dst).astype(np.int32))
+    present = np.zeros((hi + 1,), bool)
+    present[src] = True
+    present[dst] = True
+    node_ids = np.flatnonzero(present).astype(np.int32)
+    dense = np.zeros((hi + 1,), np.int32)
+    dense[node_ids] = np.arange(node_ids.size, dtype=np.int32)
+    return node_ids, dense[src], dense[dst]
+
+
+def _dedupe_pairs(src: np.ndarray, dst: np.ndarray
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """Remove duplicate (src, dst) pairs; returns them sorted by (src, dst)."""
     if int(src.shape[0]) == 0:
         return src, dst
-    order_ = jnp.lexsort((dst, src))
-    ss, ds = src[order_], dst[order_]
-    firsts = jnp.concatenate(
-        [jnp.ones((1,), bool), (ss[1:] != ss[:-1]) | (ds[1:] != ds[:-1])])
-    n_uniq = int(jnp.sum(firsts))
-    pos = jnp.nonzero(firsts, size=max(n_uniq, 1), fill_value=0)[0]
-    sel = order_[pos][:n_uniq]
-    return src[sel], dst[sel]
+    keys = np.unique((src.astype(np.int64) << 32) | dst.astype(np.int64))
+    return (keys >> 32).astype(np.int32), (keys & 0xFFFFFFFF).astype(np.int32)

@@ -137,16 +137,19 @@ def _build_shard_dir(key: np.ndarray, other: np.ndarray, d: int, ns: int,
     shard_of = key // ns
     owner_of = other // ns
     remote = owner_of != shard_of
-    bnd_sets = [np.unique(other[remote & (owner_of == o)]) for o in range(d)]
+    # owner o's boundary set: the vertices it owns that another shard's
+    # edges reference, ascending (a presence table, not a sort)
+    referenced = np.zeros((d * ns,), bool)
+    referenced[other[remote]] = True
+    bnd_sets = [np.flatnonzero(referenced[o * ns:(o + 1) * ns])
+                for o in range(d)]
     halo = max(max((v.size for v in bnd_sets), default=0), 1)
     boundary = np.zeros((d, halo), np.int32)
+    halo_pos = np.zeros((d * ns,), np.int64)
     for o, vs in enumerate(bnd_sets):
-        boundary[o, : vs.size] = (vs - o * ns).astype(np.int32)
-    gidx_e = np.where(remote, 0, other - shard_of * ns)
-    for o in range(d):
-        m = remote & (owner_of == o)
-        if m.any():
-            gidx_e[m] = ns + o * halo + np.searchsorted(bnd_sets[o], other[m])
+        boundary[o, : vs.size] = vs
+        halo_pos[o * ns + vs] = ns + o * halo + np.arange(vs.size)
+    gidx_e = np.where(remote, halo_pos[other], other - shard_of * ns)
     gidx = np.zeros((d, es), np.int32)
     seg = np.full((d, es), ns, np.int32)
     slot = np.zeros((e,), np.int32)
@@ -318,24 +321,25 @@ class GraphPlan:
         adjacency vectors.
         """
         if self._oriented is None:
-            src, dst = self.out_src, self.out_dst
-            deg = self.out_deg
+            # on the host, like the CSR build: the device sorts here compile
+            # anew for every edge count
+            src = np.asarray(self.out_src)
+            dst = np.asarray(self.out_dst)
+            deg = np.asarray(self.out_deg)
             n = self.n_nodes
             keep = (deg[src] < deg[dst]) | ((deg[src] == deg[dst]) & (src < dst))
-            n_keep = int(jnp.sum(keep))
-            perm = jnp.argsort(~keep, stable=True)[: max(n_keep, 1)]
-            osrc, odst = src[perm][:n_keep], dst[perm][:n_keep]
-            odeg = jnp.bincount(osrc, length=n)
-            max_deg = int(jnp.max(odeg)) if n_keep else 0
-            order_ = jnp.lexsort((odst, osrc))
+            osrc, odst = src[keep], dst[keep]
+            odeg = np.bincount(osrc, minlength=n)[:n].astype(np.int32)
+            max_deg = int(odeg.max()) if osrc.size else 0
+            order_ = np.lexsort((odst, osrc))
             s_sorted, d_sorted = osrc[order_], odst[order_]
-            ptr = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(odeg).astype(jnp.int32)])
+            ptr = np.concatenate([[0], np.cumsum(odeg)])
             # scatter into (n, max_deg) padded matrix; pad with n (sorts last)
-            slot = jnp.arange(n_keep, dtype=jnp.int32) - ptr[s_sorted]
-            nbr = jnp.full((n, max(max_deg, 1)), n, dtype=jnp.int32)
-            nbr = nbr.at[s_sorted, slot].set(d_sorted)
-            self._oriented = (osrc, odst, nbr, odeg.astype(jnp.int32))
+            slot = np.arange(osrc.size) - ptr[s_sorted]
+            nbr = np.full((n, max(max_deg, 1)), n, dtype=np.int32)
+            nbr[s_sorted, slot] = d_sorted
+            self._oriented = tuple(jnp.asarray(a, jnp.int32)
+                                   for a in (osrc, odst, nbr, odeg))
         return self._oriented
 
     def csr_out(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -384,9 +388,13 @@ class GraphPlan:
                 if p is not None:
                     self._in_perm_out = jnp.asarray(p)
                     return self._in_perm_out
-            # sorting the in-order edge list by (src, dst) yields out order
-            self._in_perm_out = jnp.lexsort((self.in_dst, self.in_src)) \
-                .astype(jnp.int32)
+            # sorting the in-order edge list by (src, dst) yields out order;
+            # on the host, like the CSR build (a device sort of every edge
+            # compiles anew per edge count, for minutes at tens of millions)
+            keys = ((np.asarray(self.in_src).astype(np.int64) << 32)
+                    | np.asarray(self.in_dst).astype(np.int64))
+            self._in_perm_out = jnp.asarray(
+                np.argsort(keys, kind="stable").astype(np.int32))
         return self._in_perm_out
 
     def bsr(self, block: int = DEFAULT_BLOCK
@@ -662,6 +670,6 @@ def _host_in_perm_out(info) -> Optional[np.ndarray]:
 
 
 def _device_layout(layout):
-    entry_chunk, entry_slot, local_ids, chunk_block, nb, total = layout
-    return (jnp.asarray(entry_chunk), jnp.asarray(entry_slot),
-            jnp.asarray(local_ids), jnp.asarray(chunk_block), nb, total)
+    slot_entry, local_ids, chunk_block, nb, total = layout
+    return (jnp.asarray(slot_entry), jnp.asarray(local_ids),
+            jnp.asarray(chunk_block), nb, total)

@@ -13,8 +13,8 @@ in VMEM across consecutive grid steps (zero HBM round-trips for partial
 sums).  Tile indices arrive via scalar prefetch so the DMA pipeline can look
 ahead through the sparse structure.
 
-VMEM working set per grid step: one (B,B) tile + one (B,) x block + one (B,)
-y accumulator = B²+2B floats ≈ 64 KiB + 1 KiB at B=128/f32 — comfortably
+VMEM working set per grid step: one (B,B) tile + one (1,B) x block + one
+(1,B) y accumulator = B²+2B floats ≈ 64 KiB + 1 KiB at B=128/f32 — comfortably
 inside the ~16 MiB VMEM with double buffering.
 """
 
@@ -42,11 +42,13 @@ def _bsr_spmv_kernel(rows_ref, cols_ref, a_ref, x_ref, y_ref):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    # MXU tile contraction; accumulate in f32 regardless of tile dtype
-    y_ref[...] += jnp.dot(
-        a_ref[0], x_ref[0].astype(a_ref.dtype),
-        preferred_element_type=jnp.float32,
-    )[None, :].astype(y_ref.dtype)
+    # MXU tile contraction y (1, B) += x (1, B) . A^T; accumulate in f32
+    # regardless of tile dtype (f32 tiles at full f32 precision)
+    a = a_ref[...]
+    y_ref[...] += jax.lax.dot_general(
+        x_ref[...].astype(a.dtype), a, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n_row_blocks", "interpret"))
@@ -64,20 +66,27 @@ def bsr_spmv(tiles: jax.Array, rows: jax.Array, cols: jax.Array,
       n_row_blocks: static output row-block count.
 
     Returns: (n_row_blocks, B) f32.
+
+    The vector blocks ride a leading squeezed axis — ``(n_col_blocks, 1,
+    B)`` in, ``(n_row_blocks, 1, B)`` out — so every block's last two dims
+    equal the array's, as the TPU lowering requires of a one-row block.
     """
     nnzb, b, _ = tiles.shape
+    ncb = x_blocks.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nnzb,),
         in_specs=[
-            pl.BlockSpec((1, b, b), lambda t, rows, cols: (t, 0, 0)),
-            pl.BlockSpec((1, b), lambda t, rows, cols: (cols[t], 0)),
+            pl.BlockSpec((None, b, b), lambda t, rows, cols: (t, 0, 0)),
+            pl.BlockSpec((None, 1, b), lambda t, rows, cols: (cols[t], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, b), lambda t, rows, cols: (rows[t], 0)),
+        out_specs=pl.BlockSpec((None, 1, b),
+                               lambda t, rows, cols: (rows[t], 0, 0)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         _bsr_spmv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_row_blocks, b), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_row_blocks, 1, b), jnp.float32),
         interpret=interpret,
-    )(rows, cols, tiles, x_blocks)
+    )(rows, cols, tiles, x_blocks.reshape(ncb, 1, b))
+    return y.reshape(n_row_blocks, b)

@@ -11,7 +11,7 @@ so we enumerate nonzero **block triples** (I,K)(K,J) with (I,J) nonzero —
 the block-level analogue of "for each edge, intersect neighborhoods" — and
 feed 128×128×128 dense products to the MXU (2·B³ useful flops each).  The
 elementwise mask ∘A_IJ and the global reduction run on the VPU while the
-next triple's tiles stream HBM→VMEM (grid is sequential, the scalar output
+next triple's tiles stream HBM→VMEM (grid is sequential, the (1, B) count
 block stays in VMEM the whole kernel).
 
 This is the hardware adaptation documented in DESIGN.md §2: per-edge
@@ -30,16 +30,49 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["bsr_tricount"]
 
 
-def _tricount_kernel(tij_ref, tik_ref, tkj_ref, a1_ref, a2_ref, a3_ref, acc_ref):
+# triples per pallas_call: the three prefetched index arrays of one call live
+# in SMEM (1 MiB on v5e), so longer triple streams run as a loop of calls
+TRIPLES_PER_CALL = 1 << 15
+
+
+def _tricount_kernel(nvalid_ref, tij_ref, tik_ref, tkj_ref, a1_ref, a2_ref,
+                     a3_ref, acc_ref):
     t = pl.program_id(0)
 
     @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    prod = jnp.dot(a2_ref[0], a3_ref[0], preferred_element_type=jnp.float32)
-    masked = a1_ref[0].astype(jnp.float32) * prod
-    acc_ref[0, 0] += jnp.sum(masked)
+    @pl.when(t < nvalid_ref[0])
+    def _count():
+        prod = jnp.dot(a2_ref[...], a3_ref[...],
+                       preferred_element_type=jnp.float32)
+        masked = a1_ref[...].astype(jnp.float32) * prod
+        # per-column partial counts: exact small integers, kept in int32
+        # lanes (a lane-dense (1, B) block: TPUs cannot store a VMEM scalar)
+        acc_ref[...] += jnp.sum(masked, axis=0, keepdims=True
+                                ).astype(jnp.int32)
+
+
+def _tricount_call(tiles, nvalid, t_ij, t_ik, t_kj, interpret):
+    piece = t_ij.shape[0]
+    _, b, _ = tiles.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(piece,),
+        in_specs=[
+            pl.BlockSpec((None, b, b), lambda t, nv, ij, ik, kj: (ij[t], 0, 0)),
+            pl.BlockSpec((None, b, b), lambda t, nv, ij, ik, kj: (ik[t], 0, 0)),
+            pl.BlockSpec((None, b, b), lambda t, nv, ij, ik, kj: (kj[t], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, b), lambda t, nv, ij, ik, kj: (0, 0)),
+    )
+    return pl.pallas_call(
+        _tricount_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
+        interpret=interpret,
+    )(nvalid, t_ij, t_ik, t_kj, tiles, tiles, tiles)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -51,24 +84,22 @@ def bsr_tricount(tiles: jax.Array, t_ij: jax.Array, t_ik: jax.Array,
       tiles: (nnzb, B, B) symmetric 0/1 adjacency tiles.
       t_ij, t_ik, t_kj: (n_triples,) int32 tile indices per block triple.
 
-    Returns: scalar f32 — divide by 6 for the triangle count.
+    Returns: scalar int32 — divide by 6 for the triangle count.
     """
-    n_triples = t_ij.shape[0]
-    _, b, _ = tiles.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_triples,),
-        in_specs=[
-            pl.BlockSpec((1, b, b), lambda t, ij, ik, kj: (ij[t], 0, 0)),
-            pl.BlockSpec((1, b, b), lambda t, ij, ik, kj: (ik[t], 0, 0)),
-            pl.BlockSpec((1, b, b), lambda t, ij, ik, kj: (kj[t], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda t, ij, ik, kj: (0, 0)),
-    )
-    out = pl.pallas_call(
-        _tricount_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
-    )(t_ij, t_ik, t_kj, tiles, tiles, tiles)
-    return out[0, 0]
+    n = t_ij.shape[0]
+    piece = min(n, TRIPLES_PER_CALL)
+    n_calls = -(-n // piece)
+    # pad with tile 0 to whole calls; padded steps are skipped in-kernel
+    idx = jnp.pad(jnp.stack([t_ij, t_ik, t_kj]),
+                  ((0, 0), (0, n_calls * piece - n)))
+
+    def one(i, acc):
+        lo = i * piece
+        ij, ik, kj = (jax.lax.dynamic_slice_in_dim(idx[r], lo, piece)
+                      for r in range(3))
+        nvalid = jnp.minimum(n - lo, piece).astype(jnp.int32).reshape(1)
+        return acc + _tricount_call(tiles, nvalid, ij, ik, kj, interpret)
+
+    acc = jax.lax.fori_loop(0, n_calls, one,
+                            jnp.zeros((1, tiles.shape[1]), jnp.int32))
+    return jnp.sum(acc)

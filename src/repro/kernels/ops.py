@@ -23,7 +23,7 @@ import numpy as np
 from ..core.graph import Graph
 from .bsr_tricount import bsr_tricount
 from .segment_sum import (DEFAULT_BLOCK, DEFAULT_CHUNK, chunk_layout,
-                          segment_sum_chunked)
+                          chunk_values, segment_sum_chunked)
 
 __all__ = [
     "auto_interpret",
@@ -36,8 +36,17 @@ __all__ = [
 
 
 def auto_interpret(interpret: Optional[bool]) -> bool:
+    """Resolve a kernel's interpret flag: ``None`` interprets off-TPU only.
+
+    On a TPU the kernels always compile: an interpreted kernel there would
+    quietly emulate on the host what the chip is meant to run.
+    """
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU backend: the Pallas kernels "
+                         "compile for the chip there")
     return interpret
 
 
@@ -162,11 +171,9 @@ def segment_sum_sorted(vals: jax.Array, seg_ids: jax.Array, n_segments: int,
     f32.
     """
     interpret = auto_interpret(interpret)
-    entry_chunk, entry_slot, lids, cblk, nb, total = chunk_layout(
+    slot_entry, lids, cblk, nb, _ = chunk_layout(
         np.asarray(seg_ids), n_segments, chunk)
-    cvals = jnp.zeros((total, chunk), jnp.float32)
-    cvals = cvals.at[jnp.asarray(entry_chunk), jnp.asarray(entry_slot)].set(
-        jnp.asarray(vals).astype(jnp.float32))
+    cvals = chunk_values(jnp.asarray(vals), jnp.asarray(slot_entry))
     out = segment_sum_chunked(cvals, jnp.asarray(lids), jnp.asarray(cblk),
                               nb, interpret=interpret)
     return out.reshape(-1)[: n_segments]

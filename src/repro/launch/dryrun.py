@@ -126,7 +126,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
     t1 = time.time()
     mem = compiled.memory_analysis()
-    cost = hlo_cost.xla_cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     # scan-corrected cost model (while bodies × trip counts) — see hlo_cost
     corrected = hlo_cost.analyze_hlo(hlo)

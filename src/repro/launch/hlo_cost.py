@@ -26,19 +26,8 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["analyze_hlo", "HloCost", "xla_cost_dict"]
+__all__ = ["analyze_hlo", "HloCost"]
 
-
-def xla_cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    Older jax returns one dict per device in a list; newer returns a single
-    dict.  Always yields a (possibly empty) dict.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 _DTYPE_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "f64": 8, "s8": 1, "u8": 1,
                 "pred": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4, "s64": 8,

@@ -16,7 +16,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .mesh import make_production_mesh
 
@@ -40,7 +39,7 @@ def pagerank_step_fn(mesh, axes, n_nodes: int, ns: int, es: int,
     """One distributed PageRank iteration over dst-partitioned edge shards."""
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes), P(axes), P(axes)),
         out_specs=P(axes))
     def step(src, dst_local, evalid, inv_deg_shard, pr_shard):
@@ -117,9 +116,9 @@ def run_ringo_cell(shape_name: str, multi_pod: bool) -> Dict:
             lowered = jax.jit(fn).lower(*args)
             compiled = lowered.compile()
     t1 = time.time()
-    from .hlo_cost import analyze_hlo, xla_cost_dict
+    from .hlo_cost import analyze_hlo
     mem = compiled.memory_analysis()
-    cost = xla_cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     corrected = analyze_hlo(compiled.as_text())
     return {
         "arch": "ringo-graph", "shape": shape_name, "kind": "graph",

@@ -154,7 +154,6 @@ def moe_apply_expert_tp(p: Params, x: jax.Array, cfg):
     Returns None if no mesh/rules are installed (caller falls back)."""
     import functools
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..launch.sharding import current_rules
@@ -196,10 +195,10 @@ def moe_apply_expert_tp(p: Params, x: jax.Array, cfg):
         args.append(p["wg"])
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     def run(x_l, router_w, wi, wo, *rest):
         wg = rest[0] if rest else None
         compute = x_l.dtype

@@ -39,7 +39,20 @@ from . import wire
 from .policy import ServiceError, error_from_wire
 
 __all__ = ["RemoteService", "RemoteWorkspace", "RemoteSession",
-           "RemotePending", "connect"]
+           "RemotePending", "connect", "pin_host_only"]
+
+
+def pin_host_only() -> None:
+    """Keep this process's JAX on the host CPU.
+
+    Decoded Tables and Graphs are jnp arrays, so a client touches JAX.  A
+    load-only client process calls this before any array is decoded, so it
+    never claims the accelerator its server needs: a chip belongs to one
+    process.  The setting is in-process only — a server this process spawns
+    still sees the unmodified environment.
+    """
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 
 class RemotePending:

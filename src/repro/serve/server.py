@@ -50,7 +50,7 @@ from . import wire
 from .graph_service import EdgeDelta, GraphService, Session
 from .policy import SchedulerPolicy, error_to_wire
 
-__all__ = ["GraphServer", "spawn_server", "main"]
+__all__ = ["GraphServer", "spawn_server", "publish_rmat", "main"]
 
 
 class _Connection:
@@ -448,7 +448,22 @@ def spawn_server(extra_args: Tuple[str, ...] = (), *,
     return proc, port
 
 
+def publish_rmat(service: GraphService, name: str, scale: int,
+                 edge_factor: int, seed: int):
+    """Generate an RMAT graph from ``seed``, warm its plan, and put it in
+    the service's workspace under ``name``; returns the Graph."""
+    from ..core.graph import Graph
+    from ..data.rmat import rmat_edges
+    src, dst = rmat_edges(scale, edge_factor=edge_factor, seed=seed)
+    g = Graph.from_edges(src, dst)
+    g.plan()                         # warm the shared plan once
+    service.workspace.put(name, g)
+    return g
+
+
 def main(argv: Optional[list] = None) -> int:
+    from .. import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         description="Standalone Ringo graph-analytics server")
     ap.add_argument("--host", default="127.0.0.1")
@@ -470,13 +485,8 @@ def main(argv: Optional[list] = None) -> int:
     service = GraphService(policy=SchedulerPolicy(mode=args.mode),
                            workers=max(args.workers, 0))
     if args.rmat_scale is not None:
-        from ..core.graph import Graph
-        from ..data.rmat import rmat_edges
-        src, dst = rmat_edges(args.rmat_scale, edge_factor=args.edge_factor,
-                              seed=args.seed)
-        g = Graph.from_edges(src, dst)
-        g.plan()                         # warm the shared plan once
-        service.workspace.put(args.publish, g)
+        g = publish_rmat(service, args.publish, args.rmat_scale,
+                         args.edge_factor, args.seed)
         print(f"published {args.publish!r}: {g.n_nodes} nodes "
               f"{g.n_edges} edges", flush=True)
 

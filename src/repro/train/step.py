@@ -59,15 +59,14 @@ def make_ddp_step(cfg, mesh, hyper: OptHyper = OptHyper(), *,
     sharded over ``axis``); the production path uses GSPMD instead.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     opt = get_optimizer(cfg.optimizer)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def ddp_step(params, opt_state, batch, step, residuals):
         def lf(p):

@@ -430,3 +430,11 @@ def test_capped_n_iter_matches_per_row_runs():
                 rows[i], np.asarray(A.bfs(g, int(s), n_iter=int(c),
                                           backend=backend)),
                 err_msg=f"{backend} row {i}")
+
+
+@pytest.mark.parametrize("m", [0, 1, 1024, 1025, 5000, (1 << 21) + 3])
+def test_int_cumsum_matches_cumsum(m):
+    from repro.core.engine import _int_cumsum
+    x = np.random.default_rng(m).integers(0, 50, m).astype(np.int32)
+    got = np.asarray(_int_cumsum(jnp.asarray(x)))
+    assert got.dtype == np.int32 and np.array_equal(got, np.cumsum(x))

@@ -122,3 +122,37 @@ def test_prop_degree_sum_equals_edges(edges):
     g = Graph.from_edges(s, d, dedupe=True)
     assert int(np.asarray(g.out_degrees()).sum()) == g.n_edges
     assert int(np.asarray(g.in_degrees()).sum()) == g.n_edges
+
+
+@pytest.mark.parametrize("offset", [0, 1 << 20, -5])
+def test_renumbering_table_and_sort_paths_agree(rng, offset):
+    """Compact ids take the lookup-table path, spread or negative ids the
+    sort path; both give the same ascending ids and dense endpoints."""
+    from repro.core.graph import _renumber
+    src = rng.integers(0, 300, 2000).astype(np.int32) + offset
+    dst = rng.integers(0, 300, 2000).astype(np.int32) + offset
+    node_ids, src_d, dst_d = _renumber(src, dst)
+    want = np.unique(np.concatenate([src, dst]))
+    assert node_ids.dtype == np.int32 and np.array_equal(node_ids, want)
+    assert np.array_equal(node_ids[src_d], src)
+    assert np.array_equal(node_ids[dst_d], dst)
+
+
+@pytest.mark.parametrize("block", [1 << 4, 1 << 9, 1 << 21])
+def test_rmat_stream_independent_of_blocking(monkeypatch, block):
+    """The threaded generator reproduces the sequential stream exactly."""
+    from repro.data import rmat
+    scale, ef, seed, a, b, c = 9, 8, 7, 0.57, 0.19, 0.19
+    m = ef << scale
+    r = np.random.default_rng(seed)
+    src = np.zeros(m, np.int64)
+    dst = np.zeros(m, np.int64)
+    for bit in range(scale):
+        sb = r.random(m) >= a + b
+        thresh = np.where(sb, c / (1.0 - (a + b)), a / (a + b))
+        src |= sb.astype(np.int64) << bit
+        dst |= (r.random(m) >= thresh).astype(np.int64) << bit
+    perm = r.permutation(1 << scale)
+    monkeypatch.setattr(rmat, "_BLOCK", block)
+    got = rmat.rmat_edges(scale, ef, seed)
+    assert np.array_equal(got[0], perm[src]) and np.array_equal(got[1], perm[dst])

@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import get_config, SHAPES
 from repro.launch import sharding as shlib
-from repro.launch.hlo_cost import analyze_hlo, xla_cost_dict
+from repro.launch.hlo_cost import analyze_hlo
 from repro.models import transformer as model
 
 
@@ -70,7 +70,7 @@ def test_shard_is_identity_without_rules():
 
 
 def _xla_flops(compiled):
-    return float(xla_cost_dict(compiled)["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 def test_hlo_cost_matches_xla_without_scans():
@@ -115,10 +115,10 @@ def test_hlo_cost_nested_scans():
 
 def test_hlo_cost_counts_collectives_inside_scans():
     import functools
-    from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("d",))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("d"),
+                       out_specs=P("d"))
     def h(x):
         def body(carry, _):
             gathered = jax.lax.all_gather(carry, "d", tiled=True)
