@@ -85,9 +85,11 @@ def main() -> int:
     names = {e["name"] for e in evs}
     for want in ("service.submit", "sched.queued", "sched.execute",
                  "engine.bfs", "service.cache_hit_submit",
-                 "engine.frontier_fixpoint", "engine.frontier.round"):
+                 "engine.frontier_fixpoint", "engine.frontier.sync"):
         assert want in names, f"span {want!r} missing from trace: {names}"
-    rounds = [e for e in evs if e["name"] == "engine.frontier.round"]
+    rounds = [e for e in evs if e["name"] in ("engine.frontier.push",
+                                              "engine.frontier.pull")]
+    assert rounds, f"no frontier round spans in trace: {names}"
     assert all("frontier" in e["args"] for e in rounds)
 
     # --- metrics snapshot: non-empty, and the core families are present ---
@@ -113,9 +115,10 @@ def main() -> int:
     assert report["ops"]["bfs"]["n"] >= 5
     assert report["ops"]["bfs"]["burn_rate"] > 0
 
-    # --- engine profiler: compile/execute split + report ------------------
+    # --- engine profiler: compiles, frontier phases + report --------------
     prof_series = [k for k in snap if k.startswith("engine.profile.")]
     assert prof_series, "engine profiler recorded nothing"
+    assert snap["engine.compiles"]["value"] >= 1, "no compile counted"
     prep = obs.profile_report()
     assert prep.startswith("engine profile"), prep
     assert "frontier" in prep

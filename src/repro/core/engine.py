@@ -83,7 +83,6 @@ from __future__ import annotations
 import functools
 import os
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Tuple
 
@@ -97,6 +96,7 @@ from ..kernels.bsr_spmv import bsr_spmv
 from ..kernels.ops import auto_interpret
 from ..kernels.segment_sum import (DEFAULT_BLOCK, DEFAULT_CHUNK,
                                    chunk_values, segment_sum_chunked)
+from .graph import building
 from .table import next_capacity
 
 __all__ = ["BACKENDS", "select_backend", "get_exec", "push", "pull",
@@ -118,6 +118,10 @@ _C_ROUNDS = obs.counter("engine.frontier.rounds")
 _C_DENSE = obs.counter("engine.frontier.dense_rounds")
 _C_SWITCH = obs.counter("engine.frontier.direction_switches")
 _C_RELAX = obs.counter("engine.frontier.relaxed_edges")
+# edges each direction reads: rows x frontier out-edges in a push round,
+# rows x |E| in a dense pull round
+_C_PUSH_EDGES = obs.counter("engine.frontier.push_edges")
+_C_PULL_EDGES = obs.counter("engine.frontier.pull_edges")
 _C_RETRACE = obs.counter("engine.frontier.retraces")
 # (rows, node bucket, edge budget, weighted, dtype) signatures already traced
 # by the bucketed-pow2 frontier steps: a new signature = one jit retrace
@@ -650,32 +654,34 @@ def get_exec(plan, backend: Optional[str] = None, *,
         _C_EXEC_HIT.inc()
         return ex
     _C_EXEC_MISS.inc()
-    base = (plan.n_nodes, plan.n_edges, plan.in_src, plan.in_dst,
-            plan.out_src, plan.out_dst)
-    if backend == "xla":
-        ex = XlaExec(*base)
-    elif backend == "sharded":
-        sp = plan.sharded(shards)
-        ex = ShardedExec(*base, d=sp.d, ns=sp.ns, axis=sp.axis, mesh=sp.mesh,
-                         p_es=sp.pull.es, p_halo=sp.pull.halo,
-                         q_es=sp.push.es, q_halo=sp.push.halo,
-                         p_gidx=sp.pull.gather_idx, p_seg=sp.pull.seg_local,
-                         p_slot=sp.pull.edge_slot, p_bnd=sp.pull.boundary,
-                         q_gidx=sp.push.gather_idx, q_seg=sp.push.seg_local,
-                         q_slot=sp.push.edge_slot, q_bnd=sp.push.boundary)
-    elif backend == "frontier":
-        ptr, idx, deg_pad = plan.csr_out()
-        ex = FrontierExec(*base, ptr, idx, deg_pad, plan.in_perm_out())
-    elif backend == "pallas":
-        p_src, p_lids, p_blk, nb_in, _ = plan.chunk_layout_in(chunk)
-        q_src, q_lids, q_blk, nb_out, _ = plan.chunk_layout_out(chunk)
-        ex = PallasExec(*base, p_src, p_lids, p_blk, q_src, q_lids, q_blk,
-                        nb_in=nb_in, nb_out=nb_out, interpret=interp)
-    else:
-        tiles, rows, cols, nb = plan.bsr(block)
-        tiles_t, rows_t, cols_t, _ = plan.bsr_t(block)
-        ex = BsrExec(*base, tiles, rows, cols, tiles_t, rows_t, cols_t,
-                     nb=nb, block=block, interpret=interp)
+    with building("exec." + backend):
+        base = (plan.n_nodes, plan.n_edges, plan.in_src, plan.in_dst,
+                plan.out_src, plan.out_dst)
+        if backend == "xla":
+            ex = XlaExec(*base)
+        elif backend == "sharded":
+            sp = plan.sharded(shards)
+            ex = ShardedExec(
+                *base, d=sp.d, ns=sp.ns, axis=sp.axis, mesh=sp.mesh,
+                p_es=sp.pull.es, p_halo=sp.pull.halo,
+                q_es=sp.push.es, q_halo=sp.push.halo,
+                p_gidx=sp.pull.gather_idx, p_seg=sp.pull.seg_local,
+                p_slot=sp.pull.edge_slot, p_bnd=sp.pull.boundary,
+                q_gidx=sp.push.gather_idx, q_seg=sp.push.seg_local,
+                q_slot=sp.push.edge_slot, q_bnd=sp.push.boundary)
+        elif backend == "frontier":
+            ptr, idx, deg_pad = plan.csr_out()
+            ex = FrontierExec(*base, ptr, idx, deg_pad, plan.in_perm_out())
+        elif backend == "pallas":
+            p_src, p_lids, p_blk, nb_in, _ = plan.chunk_layout_in(chunk)
+            q_src, q_lids, q_blk, nb_out, _ = plan.chunk_layout_out(chunk)
+            ex = PallasExec(*base, p_src, p_lids, p_blk, q_src, q_lids, q_blk,
+                            nb_in=nb_in, nb_out=nb_out, interpret=interp)
+        else:
+            tiles, rows, cols, nb = plan.bsr(block)
+            tiles_t, rows_t, cols_t, _ = plan.bsr_t(block)
+            ex = BsrExec(*base, tiles, rows, cols, tiles_t, rows_t, cols_t,
+                         nb=nb, block=block, interpret=interp)
     plan.execs[key] = ex
     return ex
 
@@ -704,46 +710,15 @@ def push(plan, values: jax.Array, combine: str = "sum", *,
 
 _RUNNERS = {}
 
-# (runner key, exec type, leaf shapes/dtypes) signatures already run through
-# a jitted fixpoint runner: a fresh signature means the call pays a
-# trace+lower+compile, which the profiler attributes to
-# ``engine.profile.<backend>.compile_ms`` (retrace bracketing) instead of
-# ``execute_ms``
-_PROFILED_SIGS: set = set()
 
-_BACKEND_OF = {XlaExec: "xla", PallasExec: "pallas", BsrExec: "bsr",
-               FrontierExec: "frontier", ShardedExec: "sharded"}
-
-
-def _profile_sig(key, ex, init, args) -> bool:
-    """True when this (runner, exec, shapes) signature is new — i.e. the
-    call that just ran traced and compiled."""
-    leaves = jax.tree_util.tree_leaves((ex, init, args))
-    sig = (key, type(ex),
-           tuple((tuple(getattr(leaf, "shape", ())),
-                  str(getattr(leaf, "dtype", type(leaf).__name__)))
-                 for leaf in leaves))
-    if sig in _PROFILED_SIGS:
-        return False
-    _PROFILED_SIGS.add(sig)
-    return True
-
-
-def _profile_fixpoint(key, ex, init, args, t0: float,
-                      rounds: Optional[int] = None) -> None:
-    """Record one fixpoint runner call in ``engine.profile.*`` (only
-    called when obs is enabled and outside manual regions)."""
-    dt_ms = (time.perf_counter() - t0) * 1e3
-    backend = _BACKEND_OF.get(type(ex), "xla")
-    obs.profile.record_runner(backend, _profile_sig(key, ex, init, args),
-                              dt_ms)
+def _record_halo(ex, rounds: Optional[int]) -> None:
+    """The sharded backend's static halo traffic in ``engine.profile.*``
+    (only called when obs is enabled and outside manual regions): bytes per
+    round match ``ShardPlan.halo_bytes_per_round``, and the total follows
+    when the round count is known.  Halo *time* is not attributable from
+    the host: the whole loop runs inside one shard_map region."""
     if isinstance(ex, ShardedExec):
-        # per-round halo bytes are static layout facts (matches
-        # ShardPlan.halo_bytes_per_round); per-round halo *time* is not
-        # attributable from the host — the whole loop runs inside one
-        # shard_map manual region — so loop wall time is what's recorded
-        obs.profile.record_sharded(ex.d, ex.d * ex.p_halo * 4, dt_ms,
-                                   rounds=rounds)
+        obs.profile.record_sharded(ex.d * ex.p_halo * 4, rounds=rounds)
 
 
 def _leaf_changed(o: jax.Array, n: jax.Array) -> jax.Array:
@@ -817,14 +792,25 @@ def _runner(body: Callable, fixed, manual: bool = False):
                 return final
 
         if manual:
-            def run_py(ex, *a):
+            def run(ex, *a):
                 return loop_py(ex, *a)
         else:
-            def run_py(ex, *a):
+            def run(ex, *a):
                 return ex.run_loop(loop_py, *a)
 
-        run = _RUNNERS[key] = jax.jit(run_py)
+        # the jit's name is the program's name in profiler traces:
+        # ``jit_fixpoint_pagerank_fori:fusion.7``, one per body and mode
+        run.__name__ = run.__qualname__ = _runner_name(body, fixed, manual)
+        run = _RUNNERS[key] = jax.jit(run)
     return run
+
+
+def _runner_name(body: Callable, fixed, manual: bool) -> str:
+    what = body.__name__.strip("_")
+    if what.endswith("_body"):
+        what = what[:-len("_body")]
+    mode = "tol" if fixed == "tol" else "fori" if fixed else "while"
+    return f"fixpoint_{what}_{mode}" + ("_manual" if manual else "")
 
 
 def fixpoint(plan_or_exec, body: Callable, init, *,
@@ -849,13 +835,11 @@ def fixpoint(plan_or_exec, body: Callable, init, *,
     ex = (plan_or_exec if isinstance(plan_or_exec, XlaExec)
           else get_exec(plan_or_exec, backend))
     manual = getattr(_MANUAL_REGION, "active", False)
-    # profiling brackets only make sense for real host-side calls: inside a
-    # manual region this function runs at trace time, where wall clocks
-    # measure tracing of the enclosing jit, not execution
+    # halo accounting counts real host-side calls only: inside a manual
+    # region this function runs at trace time, once per tracing
     prof = obs.REGISTRY.enabled and not manual
     if tol is not None:
         cap = np.iinfo(np.int32).max if max_iter is None else int(max_iter)
-        t0 = time.perf_counter() if prof else 0.0
         out, iters = _runner(body, "tol", manual)(ex, init, jnp.int32(cap),
                                                   jnp.float32(tol), *args)
         # skip the scalar fetch when disabled; under a jax trace (vmapped
@@ -871,21 +855,17 @@ def fixpoint(plan_or_exec, body: Callable, init, *,
                     obs.histogram(f"engine.fixpoint.tol_iters.{obs_tag}",
                                   buckets=obs.COUNT_BUCKETS).observe(n)
             if prof:
-                _profile_fixpoint(("tol", body), ex, init, args, t0,
-                                  rounds=n)
+                _record_halo(ex, n)
         return out
     if n_iter is not None:
-        t0 = time.perf_counter() if prof else 0.0
         out = _runner(body, True, manual)(ex, init, jnp.int32(n_iter), *args)
         if prof:
-            _profile_fixpoint(("fori", body), ex, init, args, t0,
-                              rounds=int(n_iter))
+            _record_halo(ex, int(n_iter))
         return out
     cap = np.iinfo(np.int32).max if max_iter is None else int(max_iter)
-    t0 = time.perf_counter() if prof else 0.0
     out = _runner(body, False, manual)(ex, init, jnp.int32(cap), *args)
     if prof:
-        _profile_fixpoint(("while", body), ex, init, args, t0)
+        _record_halo(ex, None)
     return out
 
 
@@ -938,6 +918,9 @@ def _frontier_round_out(ex, state, new, caps, t):
     return new, mask, _stats_of(mask, ex.deg_pad[: ex.n_nodes])
 
 
+# A profiler trace names the rounds' device programs after these jitted
+# functions: jit__compact and jit__frontier_push_step for a push round,
+# jit__frontier_dense_step for a pull round (bench/program_metrics.py).
 @functools.partial(jax.jit, static_argnames=("e_budget",))
 def _frontier_push_step(ex, state, f_idx, w_out, caps, t, *, e_budget):
     """One sparse push round over the compacted frontier.
@@ -1011,6 +994,14 @@ def _compact(mask, *, b):
                             side="left").astype(jnp.int32)
 
 
+def _fetch_stats(stats) -> Tuple[int, int]:
+    """The host's one blocking fetch of a round's ``(cnt, fe)``.  A device
+    gap inside its span is the round trip, not host work."""
+    with obs.TRACER.span("engine.frontier.sync"):
+        cnt, fe = (int(x) for x in np.asarray(stats))
+    return cnt, fe
+
+
 def frontier_fixpoint(plan_or_exec, init, frontier, *,
                       weights: Optional[jax.Array] = None,
                       caps=None, max_rounds: Optional[int] = None):
@@ -1062,23 +1053,11 @@ def frontier_fixpoint(plan_or_exec, init, frontier, *,
     t = 0
     reg_on = obs.REGISTRY.enabled
     prev_dense: Optional[bool] = None
-    # per-round profile timing: a round's kernel completes at the *next*
-    # iteration's stats fetch (the one host sync per round), so each round
-    # is timed from just before its step dispatch to just after that fetch
-    prof_mode: Optional[str] = None
-    prof_t0 = 0.0
     with obs.TRACER.span("engine.frontier_fixpoint", rows=k, nodes=n,
                          edges=int(ex.n_edges),
                          weighted=weights is not None) as fspan:
-        while t < bound:
-            cnt, fe = (int(x) for x in np.asarray(stats))  # one fetch/round
-            if prof_mode is not None:
-                obs.profile.record_frontier_round(
-                    prof_mode, (time.perf_counter() - prof_t0) * 1e3)
-                prof_mode = None
-            if cnt == 0:
-                break
-            tj = jnp.int32(t)
+        cnt, fe = _fetch_stats(stats)
+        while t < bound and cnt:
             dense = fe * _DENSE_EDGE_DIV >= ex.n_edges
             if reg_on:
                 _H_FRONTIER.observe(cnt)
@@ -1088,30 +1067,41 @@ def frontier_fixpoint(plan_or_exec, init, frontier, *,
                     _C_DENSE.inc()
                 if prev_dense is not None and dense != prev_dense:
                     _C_SWITCH.inc()
-                prof_mode = "dense" if dense else "sparse"
-                prof_t0 = time.perf_counter()
+            # a round's span runs from its first dispatch to the fetch of
+            # the stats its step computed: its device work lies inside it
             if dense:
-                rspan = obs.TRACER.span("engine.frontier.round", round=t,
-                                        frontier=cnt, edges=fe, mode="dense")
-                state, mask, stats = _frontier_dense_step(ex, state, w_in,
-                                                          caps_arr, tj)
+                with obs.TRACER.span("engine.frontier.pull", round=t,
+                                     frontier=cnt, edges=fe) as rspan:
+                    state, mask, stats = _frontier_dense_step(
+                        ex, state, w_in, caps_arr, jnp.int32(t))
+                    next_cnt, next_fe = _fetch_stats(stats)
             else:
                 b = min(next_capacity(cnt, minimum=_MIN_BUCKET),
                         next_capacity(max(n, 1)))
-                f_idx = _compact(mask, b=b)
                 eb = next_capacity(max(fe, 1), minimum=_MIN_BUCKET)
                 shape_sig = (k, b, eb, w_out is None, str(state.dtype))
                 if shape_sig not in _TRACED_SHAPES:
                     _TRACED_SHAPES.add(shape_sig)
                     if reg_on:
                         _C_RETRACE.inc()
-                rspan = obs.TRACER.span("engine.frontier.round", round=t,
-                                        frontier=cnt, edges=fe,
-                                        mode="sparse", bucket=b, e_budget=eb)
-                state, mask, stats = _frontier_push_step(ex, state, f_idx,
-                                                         w_out, caps_arr, tj,
-                                                         e_budget=eb)
-            rspan.finish()
+                with obs.TRACER.span("engine.frontier.push", round=t,
+                                     frontier=cnt, edges=fe, bucket=b,
+                                     e_budget=eb) as rspan:
+                    f_idx = _compact(mask, b=b)
+                    state, mask, stats = _frontier_push_step(
+                        ex, state, f_idx, w_out, caps_arr, jnp.int32(t),
+                        e_budget=eb)
+                    next_cnt, next_fe = _fetch_stats(stats)
+            if reg_on:
+                if dense:
+                    _C_PULL_EDGES.inc(k * int(ex.n_edges))
+                else:
+                    _C_PUSH_EDGES.inc(k * fe)
+                if rspan.duration_s is not None:
+                    obs.profile.record_frontier_round(
+                        "dense" if dense else "sparse",
+                        rspan.duration_s * 1e3)
+            cnt, fe = next_cnt, next_fe
             prev_dense = dense
             t += 1
         fspan.set(rounds=t)

@@ -22,6 +22,9 @@ Capacities are power-of-two bucketed like tables.
 
 from __future__ import annotations
 
+import contextlib
+import threading
+import time
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -33,7 +36,7 @@ from .. import obs
 from .provenance import track, version_of
 from .table import next_capacity
 
-__all__ = ["Graph", "EdgeDelta", "INVALID_ID"]
+__all__ = ["Graph", "EdgeDelta", "INVALID_ID", "building"]
 
 INVALID_ID = np.iinfo(np.int32).max
 
@@ -41,6 +44,33 @@ _log = obs.get_logger(__name__)
 _C_PLAN_HIT = obs.counter("engine.plan_cache.hits")
 _C_PLAN_MISS = obs.counter("engine.plan_cache.misses")
 _C_PLAN_PATCH = obs.counter("engine.plan_cache.patched")
+_H_BUILD = obs.histogram("plan.build_ms", buckets=obs.LONG_BUCKETS_MS)
+_BUILDING = threading.local()
+
+
+@contextlib.contextmanager
+def building(what: str):
+    """One build of a graph or plan structure, in a ``plan.build.<what>``
+    span.
+
+    The span times the host's part: its sorts and layouts, dispatching and
+    compiling device work, and waiting on device results it reads.  It does
+    not wait for the device work it leaves running, which the next reader
+    of those arrays waits for.  Only an outermost build observes its
+    milliseconds into ``plan.build_ms``: a build nested in another (the
+    frontier exec's CSR inside ``exec.frontier``) counts once, in the
+    outer one.
+    """
+    depth = getattr(_BUILDING, "depth", 0)
+    _BUILDING.depth = depth + 1
+    t0 = time.perf_counter()
+    try:
+        with obs.TRACER.span("plan.build." + what):
+            yield
+    finally:
+        _BUILDING.depth = depth
+    if depth == 0:
+        _H_BUILD.observe((time.perf_counter() - t0) * 1e3)
 
 
 @dataclass(frozen=True)
@@ -163,22 +193,24 @@ class Graph:
         longer than the host sort it would replace.  The CSR arrays are
         uploaded once, built.
         """
-        src = np.asarray(src, dtype=np.int32).reshape(-1)
-        dst = np.asarray(dst, dtype=np.int32).reshape(-1)
-        e = int(src.shape[0])
-        node_cap = next_capacity(max(n_nodes, 1))
-        edge_cap = next_capacity(max(e, 1))
+        with building("graph"):
+            src = np.asarray(src, dtype=np.int32).reshape(-1)
+            dst = np.asarray(dst, dtype=np.int32).reshape(-1)
+            e = int(src.shape[0])
+            node_cap = next_capacity(max(n_nodes, 1))
+            edge_cap = next_capacity(max(e, 1))
 
-        ids = np.full((node_cap,), INVALID_ID, np.int32)
-        ids[:n_nodes] = (np.arange(n_nodes, dtype=np.int32)
-                         if node_ids is None
-                         else np.asarray(node_ids, np.int32)[:n_nodes])
+            ids = np.full((node_cap,), INVALID_ID, np.int32)
+            ids[:n_nodes] = (np.arange(n_nodes, dtype=np.int32)
+                             if node_ids is None
+                             else np.asarray(node_ids, np.int32)[:n_nodes])
 
-        out_ptr, out_idx = _csr_from_pairs(src, dst, node_cap, edge_cap)
-        in_ptr, in_idx = _csr_from_pairs(dst, src, node_cap, edge_cap)
-        return cls(n_nodes=n_nodes, n_edges=e, node_ids=jnp.asarray(ids),
-                   out_ptr=jnp.asarray(out_ptr), out_idx=jnp.asarray(out_idx),
-                   in_ptr=jnp.asarray(in_ptr), in_idx=jnp.asarray(in_idx))
+            out_ptr, out_idx = _csr_from_pairs(src, dst, node_cap, edge_cap)
+            in_ptr, in_idx = _csr_from_pairs(dst, src, node_cap, edge_cap)
+            return cls(n_nodes=n_nodes, n_edges=e, node_ids=jnp.asarray(ids),
+                       out_ptr=jnp.asarray(out_ptr),
+                       out_idx=jnp.asarray(out_idx),
+                       in_ptr=jnp.asarray(in_ptr), in_idx=jnp.asarray(in_idx))
 
     @classmethod
     def from_edges(cls, src, dst, dedupe: bool = True,
@@ -188,19 +220,20 @@ class Graph:
         Node set = union of endpoint ids (paper §2.4: "Nodes V are defined by
         unique values in columns S and D").
         """
-        src = np.asarray(src, dtype=np.int32).reshape(-1)
-        dst = np.asarray(dst, dtype=np.int32).reshape(-1)
-        if drop_self_loops:
-            keep = src != dst
-            src, dst = src[keep], dst[keep]
-        if src.shape[0] == 0:
-            return cls.from_dense_edges(src, dst, 0)
+        with building("graph"):
+            src = np.asarray(src, dtype=np.int32).reshape(-1)
+            dst = np.asarray(dst, dtype=np.int32).reshape(-1)
+            if drop_self_loops:
+                keep = src != dst
+                src, dst = src[keep], dst[keep]
+            if src.shape[0] == 0:
+                return cls.from_dense_edges(src, dst, 0)
 
-        node_ids, src_d, dst_d = _renumber(src, dst)
-        if dedupe:
-            src_d, dst_d = _dedupe_pairs(src_d, dst_d)
-        return cls.from_dense_edges(src_d, dst_d, int(node_ids.size),
-                                    node_ids=node_ids)
+            node_ids, src_d, dst_d = _renumber(src, dst)
+            if dedupe:
+                src_d, dst_d = _dedupe_pairs(src_d, dst_d)
+            return cls.from_dense_edges(src_d, dst_d, int(node_ids.size),
+                                        node_ids=node_ids)
 
     # -- accessors ---------------------------------------------------------------
     @property
@@ -250,9 +283,11 @@ class Graph:
             _C_PLAN_MISS.inc()
             if self._delta is not None:
                 _C_PLAN_PATCH.inc()
-                self._plan = GraphPlan.patch(self, self._delta)
+                with building("patch"):
+                    self._plan = GraphPlan.patch(self, self._delta)
             else:
-                self._plan = GraphPlan.build(self)
+                with building("base"):
+                    self._plan = GraphPlan.build(self)
         else:
             _C_PLAN_HIT.inc()
         return self._plan

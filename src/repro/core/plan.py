@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .graph import EdgeDelta, Graph
+from .graph import EdgeDelta, Graph, building
 from ..kernels.segment_sum import DEFAULT_BLOCK, DEFAULT_CHUNK, chunk_layout
 
 __all__ = ["GraphPlan", "ShardPlan", "EVICTABLE_FAMILIES"]
@@ -298,17 +298,18 @@ class GraphPlan:
         back to a full rebuild.
         """
         if self._undirected is None:
-            info = self._info
-            if info is not None and info.insert_only:
-                osrc = np.asarray(self.graph.original_of(info.add_src))
-                odst = np.asarray(self.graph.original_of(info.add_dst))
-                keep = osrc != odst
-                self._undirected = self._parent.undirected().apply_delta(
-                    EdgeDelta.inserts(
-                        np.concatenate([osrc[keep], odst[keep]]),
-                        np.concatenate([odst[keep], osrc[keep]])))
-            else:
-                self._undirected = self.graph.to_undirected()
+            with building("undirected"):
+                info = self._info
+                if info is not None and info.insert_only:
+                    osrc = np.asarray(self.graph.original_of(info.add_src))
+                    odst = np.asarray(self.graph.original_of(info.add_dst))
+                    keep = osrc != odst
+                    self._undirected = self._parent.undirected().apply_delta(
+                        EdgeDelta.inserts(
+                            np.concatenate([osrc[keep], odst[keep]]),
+                            np.concatenate([odst[keep], osrc[keep]])))
+                else:
+                    self._undirected = self.graph.to_undirected()
         return self._undirected
 
     def oriented(self) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -321,25 +322,28 @@ class GraphPlan:
         adjacency vectors.
         """
         if self._oriented is None:
-            # on the host, like the CSR build: the device sorts here compile
-            # anew for every edge count
-            src = np.asarray(self.out_src)
-            dst = np.asarray(self.out_dst)
-            deg = np.asarray(self.out_deg)
-            n = self.n_nodes
-            keep = (deg[src] < deg[dst]) | ((deg[src] == deg[dst]) & (src < dst))
-            osrc, odst = src[keep], dst[keep]
-            odeg = np.bincount(osrc, minlength=n)[:n].astype(np.int32)
-            max_deg = int(odeg.max()) if osrc.size else 0
-            order_ = np.lexsort((odst, osrc))
-            s_sorted, d_sorted = osrc[order_], odst[order_]
-            ptr = np.concatenate([[0], np.cumsum(odeg)])
-            # scatter into (n, max_deg) padded matrix; pad with n (sorts last)
-            slot = np.arange(osrc.size) - ptr[s_sorted]
-            nbr = np.full((n, max(max_deg, 1)), n, dtype=np.int32)
-            nbr[s_sorted, slot] = d_sorted
-            self._oriented = tuple(jnp.asarray(a, jnp.int32)
-                                   for a in (osrc, odst, nbr, odeg))
+            with building("oriented"):
+                # on the host, like the CSR build: the device sorts here
+                # compile anew for every edge count
+                src = np.asarray(self.out_src)
+                dst = np.asarray(self.out_dst)
+                deg = np.asarray(self.out_deg)
+                n = self.n_nodes
+                keep = (deg[src] < deg[dst]) | \
+                    ((deg[src] == deg[dst]) & (src < dst))
+                osrc, odst = src[keep], dst[keep]
+                odeg = np.bincount(osrc, minlength=n)[:n].astype(np.int32)
+                max_deg = int(odeg.max()) if osrc.size else 0
+                order_ = np.lexsort((odst, osrc))
+                s_sorted, d_sorted = osrc[order_], odst[order_]
+                ptr = np.concatenate([[0], np.cumsum(odeg)])
+                # scatter into (n, max_deg) padded matrix; pad with n
+                # (sorts last)
+                slot = np.arange(osrc.size) - ptr[s_sorted]
+                nbr = np.full((n, max(max_deg, 1)), n, dtype=np.int32)
+                nbr[s_sorted, slot] = d_sorted
+                self._oriented = tuple(jnp.asarray(a, jnp.int32)
+                                       for a in (osrc, odst, nbr, odeg))
         return self._oriented
 
     def csr_out(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -352,10 +356,11 @@ class GraphPlan:
         contribute no edges.
         """
         if self._csr_out is None:
-            g, n = self.graph, self.n_nodes
-            deg_pad = jnp.concatenate(
-                [self.out_deg, jnp.zeros((1,), self.out_deg.dtype)])
-            self._csr_out = (g.out_ptr[: n + 1], g.out_idx, deg_pad)
+            with building("csr_out"):
+                g, n = self.graph, self.n_nodes
+                deg_pad = jnp.concatenate(
+                    [self.out_deg, jnp.zeros((1,), self.out_deg.dtype)])
+                self._csr_out = (g.out_ptr[: n + 1], g.out_idx, deg_pad)
         return self._csr_out
 
     def csr_in(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -367,10 +372,11 @@ class GraphPlan:
         consumes this yet.
         """
         if self._csr_in is None:
-            g, n = self.graph, self.n_nodes
-            deg_pad = jnp.concatenate(
-                [self.in_deg, jnp.zeros((1,), self.in_deg.dtype)])
-            self._csr_in = (g.in_ptr[: n + 1], g.in_idx, deg_pad)
+            with building("csr_in"):
+                g, n = self.graph, self.n_nodes
+                deg_pad = jnp.concatenate(
+                    [self.in_deg, jnp.zeros((1,), self.in_deg.dtype)])
+                self._csr_in = (g.in_ptr[: n + 1], g.in_idx, deg_pad)
         return self._csr_in
 
     def in_perm_out(self) -> jax.Array:
@@ -382,33 +388,36 @@ class GraphPlan:
         gather re-keys weights once per call.
         """
         if self._in_perm_out is None:
-            info = self._info
-            if info is not None:
-                p = _host_in_perm_out(info)
-                if p is not None:
-                    self._in_perm_out = jnp.asarray(p)
-                    return self._in_perm_out
-            # sorting the in-order edge list by (src, dst) yields out order;
-            # on the host, like the CSR build (a device sort of every edge
-            # compiles anew per edge count, for minutes at tens of millions)
-            keys = ((np.asarray(self.in_src).astype(np.int64) << 32)
-                    | np.asarray(self.in_dst).astype(np.int64))
-            self._in_perm_out = jnp.asarray(
-                np.argsort(keys, kind="stable").astype(np.int32))
+            with building("in_perm_out"):
+                info = self._info
+                if info is not None:
+                    p = _host_in_perm_out(info)
+                    if p is not None:
+                        self._in_perm_out = jnp.asarray(p)
+                        return self._in_perm_out
+                # sorting the in-order edge list by (src, dst) yields out
+                # order; on the host, like the CSR build (a device sort of
+                # every edge compiles anew per edge count, for minutes at
+                # tens of millions)
+                keys = ((np.asarray(self.in_src).astype(np.int64) << 32)
+                        | np.asarray(self.in_dst).astype(np.int64))
+                self._in_perm_out = jnp.asarray(
+                    np.argsort(keys, kind="stable").astype(np.int32))
         return self._in_perm_out
 
     def bsr(self, block: int = DEFAULT_BLOCK
             ) -> Tuple[jax.Array, jax.Array, jax.Array, int]:
         """Unweighted BSR tiles of M[dst, src] (the pull/SpMV layout)."""
         if block not in self._bsr:
-            patched = self._patched_bsr(block, transpose=False)
-            if patched is not None:
-                self._bsr[block] = patched
-            else:
-                from ..kernels.ops import edges_to_bsr
-                self._bsr[block] = edges_to_bsr(np.asarray(self.in_src),
-                                                np.asarray(self.in_dst),
-                                                self.n_nodes, block=block)
+            with building("bsr"):
+                patched = self._patched_bsr(block, transpose=False)
+                if patched is not None:
+                    self._bsr[block] = patched
+                else:
+                    from ..kernels.ops import edges_to_bsr
+                    self._bsr[block] = edges_to_bsr(np.asarray(self.in_src),
+                                                    np.asarray(self.in_dst),
+                                                    self.n_nodes, block=block)
         return self._bsr[block]
 
     def bsr_t(self, block: int = DEFAULT_BLOCK
@@ -422,15 +431,17 @@ class GraphPlan:
         path as the pull.
         """
         if block not in self._bsr_t:
-            patched = self._patched_bsr(block, transpose=True)
-            if patched is not None:
-                self._bsr_t[block] = patched
-            else:
-                from ..kernels.ops import edges_to_bsr
-                # edges_to_bsr(a, b) builds M[b, a]: pass (dst, src) for M[src, dst]
-                self._bsr_t[block] = edges_to_bsr(np.asarray(self.out_dst),
-                                                  np.asarray(self.out_src),
-                                                  self.n_nodes, block=block)
+            with building("bsr_t"):
+                patched = self._patched_bsr(block, transpose=True)
+                if patched is not None:
+                    self._bsr_t[block] = patched
+                else:
+                    from ..kernels.ops import edges_to_bsr
+                    # edges_to_bsr(a, b) builds M[b, a]: pass (dst, src) for
+                    # M[src, dst]
+                    self._bsr_t[block] = edges_to_bsr(
+                        np.asarray(self.out_dst), np.asarray(self.out_src),
+                        self.n_nodes, block=block)
         return self._bsr_t[block]
 
     def _patched_bsr(self, block: int, transpose: bool):
@@ -474,32 +485,35 @@ class GraphPlan:
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Tile triples (I,J),(I,K),(K,J) for the BSR triangle kernel."""
         if block not in self._tri_triples:
-            _, rows, cols, _ = self.bsr(block)
-            parent = self._parent
-            if parent is not None and block in parent._tri_triples \
-                    and block in parent._bsr \
-                    and parent._bsr[block][1] is rows:
-                # patched BSR kept the parent's tile structure -> the
-                # (I,J),(I,K),(K,J) triples are byte-identical
-                self._tri_triples[block] = parent._tri_triples[block]
-            else:
-                from ..kernels.ops import build_block_triples
-                self._tri_triples[block] = build_block_triples(
-                    np.asarray(rows), np.asarray(cols))
+            with building("tri_triples"):
+                _, rows, cols, _ = self.bsr(block)
+                parent = self._parent
+                if parent is not None and block in parent._tri_triples \
+                        and block in parent._bsr \
+                        and parent._bsr[block][1] is rows:
+                    # patched BSR kept the parent's tile structure -> the
+                    # (I,J),(I,K),(K,J) triples are byte-identical
+                    self._tri_triples[block] = parent._tri_triples[block]
+                else:
+                    from ..kernels.ops import build_block_triples
+                    self._tri_triples[block] = build_block_triples(
+                        np.asarray(rows), np.asarray(cols))
         return self._tri_triples[block]
 
     def chunk_layout_in(self, chunk: int = DEFAULT_CHUNK):
         """Pallas chunk structure for per-destination (pull) reductions."""
         if chunk not in self._chunks_in:
-            self._chunks_in[chunk] = _device_layout(
-                chunk_layout(np.asarray(self.in_dst), self.n_nodes, chunk))
+            with building("chunk_layout_in"):
+                self._chunks_in[chunk] = _device_layout(chunk_layout(
+                    np.asarray(self.in_dst), self.n_nodes, chunk))
         return self._chunks_in[chunk]
 
     def chunk_layout_out(self, chunk: int = DEFAULT_CHUNK):
         """Pallas chunk structure for per-source (push) reductions."""
         if chunk not in self._chunks_out:
-            self._chunks_out[chunk] = _device_layout(
-                chunk_layout(np.asarray(self.out_src), self.n_nodes, chunk))
+            with building("chunk_layout_out"):
+                self._chunks_out[chunk] = _device_layout(chunk_layout(
+                    np.asarray(self.out_src), self.n_nodes, chunk))
         return self._chunks_out[chunk]
 
     def sharded(self, n_shards: int, axis: Optional[str] = None) -> ShardPlan:
@@ -520,26 +534,28 @@ class GraphPlan:
         if d < 1:
             raise ValueError(f"sharded() needs >= 1 shard, got {d}")
         if d not in self._sharded:
-            mesh = graph_mesh(d, axis)
-            # replicated placement: the engine's manual regions take every
-            # input full-shape (in_specs P()) and slice their own shard via
-            # axis_index — see ShardedExec in core/engine.py for why GSPMD
-            # is given no sharding decisions at all on this path
-            spec = graph_replicated_spec(mesh)
-            n = self.n_nodes
-            ns = max(-(-n // d) if d else 1, 1)
-            pull = _build_shard_dir(np.asarray(self.in_dst),
-                                    np.asarray(self.in_src), d, ns, spec)
-            push = _build_shard_dir(np.asarray(self.out_src),
-                                    np.asarray(self.out_dst), d, ns, spec)
-            pad = d * ns - n
-            out_deg = jax.device_put(
-                jnp.pad(self.out_deg, (0, pad)), spec)
-            in_deg = jax.device_put(
-                jnp.pad(self.in_deg, (0, pad)), spec)
-            self._sharded[d] = ShardPlan(d=d, ns=ns, axis=axis, mesh=mesh,
-                                         pull=pull, push=push,
-                                         out_deg=out_deg, in_deg=in_deg)
+            with building("sharded"):
+                mesh = graph_mesh(d, axis)
+                # replicated placement: the engine's manual regions take
+                # every input full-shape (in_specs P()) and slice their own
+                # shard via axis_index — see ShardedExec in core/engine.py
+                # for why GSPMD is given no sharding decisions at all on
+                # this path
+                spec = graph_replicated_spec(mesh)
+                n = self.n_nodes
+                ns = max(-(-n // d) if d else 1, 1)
+                pull = _build_shard_dir(np.asarray(self.in_dst),
+                                        np.asarray(self.in_src), d, ns, spec)
+                push = _build_shard_dir(np.asarray(self.out_src),
+                                        np.asarray(self.out_dst), d, ns, spec)
+                pad = d * ns - n
+                out_deg = jax.device_put(
+                    jnp.pad(self.out_deg, (0, pad)), spec)
+                in_deg = jax.device_put(
+                    jnp.pad(self.in_deg, (0, pad)), spec)
+                self._sharded[d] = ShardPlan(d=d, ns=ns, axis=axis, mesh=mesh,
+                                             pull=pull, push=push,
+                                             out_deg=out_deg, in_deg=in_deg)
         return self._sharded[d]
 
     # -- byte accounting + eviction ----------------------------------------------

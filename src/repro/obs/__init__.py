@@ -36,8 +36,9 @@ On top of the raw instruments sits the **judgment layer** (PR 10):
 * :data:`FLIGHT` (:class:`~repro.obs.flight.FlightRecorder`) — exemplars
   of slow/errored/expired requests frozen at completion time (they
   survive trace-ring wrap) and :func:`debug_bundle` postmortem artifacts;
-* :mod:`repro.obs.profile` — ``engine.profile.*`` instruments (compile vs
-  execute per backend, frontier round phases, sharded halo traffic) and
+* :mod:`repro.obs.profile` — ``engine.compiles`` / ``engine.compile_ms``
+  from JAX's own compile events, ``engine.profile.*`` instruments
+  (frontier round phases, sharded halo traffic) and
   :func:`profile_report`; ``python -m repro.obs.report`` renders the
   dashboard against a live server or a saved bundle.
 """
@@ -50,7 +51,7 @@ from typing import Any, Dict, Optional, Union
 from . import log as _log
 from .log import StructLogger, format_event, get_logger
 from .metrics import (BYTE_BUCKETS, COUNT_BUCKETS, DEFAULT_BUCKETS_MS,
-                      Counter, Gauge, Histogram, Registry,
+                      LONG_BUCKETS_MS, Counter, Gauge, Histogram, Registry,
                       quantile_from_snapshot)
 from .trace import NOOP_SPAN, Span, Tracer
 from .slo import Objective, SLOTracker
@@ -62,7 +63,8 @@ __all__ = [
     "Registry", "Tracer", "Span", "NOOP_SPAN",
     "Counter", "Gauge", "Histogram", "StructLogger",
     "Objective", "SLOTracker", "FlightRecorder", "profile",
-    "DEFAULT_BUCKETS_MS", "COUNT_BUCKETS", "BYTE_BUCKETS",
+    "DEFAULT_BUCKETS_MS", "LONG_BUCKETS_MS", "COUNT_BUCKETS",
+    "BYTE_BUCKETS",
     "enable", "disable", "enabled",
     "counter", "gauge", "histogram", "quantile_from_snapshot",
     "span", "instant", "add_complete", "new_trace_id", "current_trace",
@@ -93,8 +95,10 @@ FLIGHT = FlightRecorder(TRACER, REGISTRY, slo=SLO)
 # inside trace.py to keep that module free of a metrics import)
 TRACER.drop_hook = REGISTRY.counter("trace.dropped").inc
 
-# bind the engine-profiling instruments to the global registry
+# bind the engine-profiling instruments to the global registry, and count
+# JAX's own backend compiles into it (one listener per process)
 profile.bind(REGISTRY)
+profile.listen_compiles()
 
 
 def enable(*, metrics: bool = True, tracing: bool = True) -> None:

@@ -30,7 +30,8 @@ from bisect import bisect_left
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Type
 
 __all__ = ["Registry", "Counter", "Gauge", "Histogram",
-           "DEFAULT_BUCKETS_MS", "COUNT_BUCKETS", "BYTE_BUCKETS",
+           "DEFAULT_BUCKETS_MS", "LONG_BUCKETS_MS", "COUNT_BUCKETS",
+           "BYTE_BUCKETS",
            "quantile_from_snapshot"]
 
 # latency-ish buckets (milliseconds): sub-0.1ms cache hits up to multi-second
@@ -38,6 +39,11 @@ __all__ = ["Registry", "Counter", "Gauge", "Histogram",
 DEFAULT_BUCKETS_MS: Tuple[float, ...] = (
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
     250.0, 500.0, 1000.0, 2500.0, 5000.0, 10000.0)
+
+# set-up buckets (milliseconds): compiles and plan builds run from
+# milliseconds on small graphs to minutes at tens of millions of edges
+LONG_BUCKETS_MS: Tuple[float, ...] = DEFAULT_BUCKETS_MS + (
+    25000.0, 60000.0, 150000.0, 400000.0)
 
 # size/iteration buckets (powers of two): frontier sizes, batch sizes,
 # solver iteration counts

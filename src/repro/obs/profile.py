@@ -2,32 +2,24 @@
 
 PR 7's spans say *that* an engine call took 80 ms; this layer says *why*:
 
-* **compile vs execute per backend** — the engine brackets every jitted
-  fixpoint runner call with retrace detection (a module-level signature set
-  keyed on the runner identity plus the argument shapes/dtypes): the first
-  call for a new signature pays trace+lower+compile and lands in
-  ``engine.profile.<backend>.compile_ms``; repeat calls land in
-  ``engine.profile.<backend>.execute_ms``.  Execute time is dispatch-to-
-  return wall time — on the CPU backends used here that is effectively the
-  run time, but it is *not* a device-synchronized measurement (the
-  authoritative per-request engine time remains ``sched.engine_ms``).
-* **per-round frontier phase timing** — the frontier host loop's
-  dense/sparse step durations land in
-  ``engine.profile.frontier.{dense,sparse}_ms`` (one observation per
-  round, measured dispatch-to-stats-fetch so it covers the round's actual
-  compute).
+* **compiles** — ``engine.compiles`` (a counter) and ``engine.compile_ms``
+  (a histogram) count and time every backend compile, read from JAX's own
+  ``/jax/core/compile/backend_compile_duration`` event by one listener per
+  process (:func:`listen_compiles`), whatever code asked for the compile.
+* **per-round frontier phase timing** — each frontier round's span
+  (``engine.frontier.push`` / ``engine.frontier.pull``, from the round's
+  first dispatch to the fetch of its stats, so it covers the round's device
+  work) lands its duration in ``engine.profile.frontier.{sparse,dense}_ms``.
 * **sharded halo traffic** — the sharded backend runs its whole fixpoint
   inside one ``shard_map`` region, so per-round halo *time* is not
   attributable from the host; what is exact is the per-round halo *bytes*
   (``d * halo_width * itemsize``, the same figure as
-  ``ShardPlan.halo_bytes_per_round``) and the whole-loop wall time.  Both
-  are recorded, plus total exchanged bytes when the round count is known
-  (tol/n_iter modes).
+  ``ShardPlan.halo_bytes_per_round``), and the total exchanged bytes when
+  the round count is known (tol/n_iter modes).
 
-Everything lands in ordinary registry instruments under
-``engine.profile.*`` — snapshot/Prometheus/wire exposition come for free —
-and :func:`profile_report` renders any snapshot (live, remote, or from a
-saved debug bundle) as a text table.
+Everything lands in ordinary registry instruments — snapshot/Prometheus/
+wire exposition come for free — and :func:`profile_report` renders any
+snapshot (live, remote, or from a saved debug bundle) as a text table.
 
 The module is bound to a registry by ``obs/__init__`` (:func:`bind`); all
 record calls are no-ops until then and the engine additionally guards them
@@ -39,15 +31,18 @@ from __future__ import annotations
 import threading
 from typing import Any, Dict, Optional
 
-from .metrics import (BYTE_BUCKETS, DEFAULT_BUCKETS_MS, Registry,
-                      quantile_from_snapshot)
+from .metrics import (BYTE_BUCKETS, DEFAULT_BUCKETS_MS, LONG_BUCKETS_MS,
+                      Registry, quantile_from_snapshot)
 
-__all__ = ["bind", "record_runner", "record_frontier_round",
+__all__ = ["bind", "listen_compiles", "record_frontier_round",
            "record_sharded", "profile_report"]
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _REG: Optional[Registry] = None
 _lock = threading.Lock()
 _cache: Dict[str, Any] = {}
+_listening = False
 
 
 def bind(registry: Registry) -> None:
@@ -83,13 +78,26 @@ def _counter(name: str):
     return c
 
 
-def record_runner(backend: str, compiled: bool, dt_ms: float) -> None:
-    """One fixpoint runner invocation: ``compiled`` means this call paid a
-    trace+compile for a fresh signature (retrace bracketing)."""
-    kind = "compile_ms" if compiled else "execute_ms"
-    h = _hist(f"engine.profile.{backend}.{kind}")
-    if h is not None:
-        h.observe(dt_ms)
+def _on_duration(event: str, duration_secs: float, **kwargs: Any) -> None:
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    c = _counter("engine.compiles")
+    h = _hist("engine.compile_ms", LONG_BUCKETS_MS)
+    if c is not None and h is not None:
+        c.inc()
+        h.observe(duration_secs * 1e3)
+
+
+def listen_compiles() -> None:
+    """Count JAX's backend compiles into ``engine.compiles`` and
+    ``engine.compile_ms``; registers its listener once per process."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def record_frontier_round(mode: str, dt_ms: float) -> None:
@@ -100,13 +108,10 @@ def record_frontier_round(mode: str, dt_ms: float) -> None:
         h.observe(dt_ms)
 
 
-def record_sharded(d: int, halo_bytes_per_round: int, dt_ms: float,
+def record_sharded(halo_bytes_per_round: int,
                    rounds: Optional[int] = None) -> None:
-    """One sharded fixpoint loop: device count, per-round halo bytes, and
-    whole-loop wall time; total bytes when the round count is static."""
-    h = _hist("engine.profile.sharded.loop_ms")
-    if h is not None:
-        h.observe(dt_ms)
+    """One sharded fixpoint loop: per-round halo bytes, and the total when
+    the round count is static."""
     hb = _hist("engine.profile.sharded.halo_bytes_per_round", BYTE_BUCKETS)
     if hb is not None:
         hb.observe(float(halo_bytes_per_round))
@@ -139,8 +144,9 @@ def _hist_row(name: str, snap: Dict[str, Any]) -> tuple:
 
 
 def profile_report(snapshot: Optional[Dict[str, Any]] = None) -> str:
-    """Text table of every ``engine.profile.*`` instrument in a registry
-    snapshot (defaults to the bound registry's live snapshot).
+    """Text table of ``engine.compile_ms`` and every ``engine.profile.*``
+    instrument in a registry snapshot (defaults to the bound registry's
+    live snapshot).
 
     Works identically against a remote server's shipped snapshot or the
     ``metrics`` block of a saved debug bundle — the renderer only needs
@@ -153,17 +159,20 @@ def profile_report(snapshot: Optional[Dict[str, Any]] = None) -> str:
     rows = []
     counters = []
     for name in sorted(snapshot):
-        if not name.startswith("engine.profile."):
+        if name == "engine.compile_ms":
+            short = "compile_ms"
+        elif name.startswith("engine.profile."):
+            short = name[len("engine.profile."):]
+        else:
             continue
         snap = snapshot[name]
-        short = name[len("engine.profile."):]
         if snap.get("type") == "histogram":
             rows.append(_hist_row(short, snap))
         else:
             counters.append((short, snap.get("value", 0)))
     lines = ["engine profile"]
     if not rows and not counters:
-        lines.append("  (no engine.profile.* samples recorded)")
+        lines.append("  (no compile or engine.profile.* samples recorded)")
         return "\n".join(lines) + "\n"
     if rows:
         w = max(len(r[0]) for r in rows)
@@ -176,9 +185,12 @@ def profile_report(snapshot: Optional[Dict[str, Any]] = None) -> str:
     for name, v in counters:
         lines.append(f"  {name} = {v:g}")
     # companion engine counters that contextualize the phases
-    extras = [n for n in ("engine.frontier.rounds",
+    extras = [n for n in ("engine.compiles",
+                          "engine.frontier.rounds",
                           "engine.frontier.dense_rounds",
                           "engine.frontier.direction_switches",
+                          "engine.frontier.push_edges",
+                          "engine.frontier.pull_edges",
                           "engine.frontier.retraces",
                           "engine.exec_cache.hits",
                           "engine.exec_cache.misses")

@@ -25,6 +25,15 @@ shared by every thread in the process, which is all the viewer needs.
 Disabled mode is allocation-free: :meth:`Tracer.span` returns a shared
 no-op singleton and :meth:`instant`/:meth:`add_complete` return before
 building anything.
+
+**The profiler's clock.**  While a ``jax.profiler`` session records, every
+live span also opens a ``jax.profiler.TraceAnnotation`` (a TraceMe) of the
+same name on the thread that opens it, closed when the span finishes, so
+the program's spans land in the profiler trace beside the device ops they
+enclose.  The check is one ``TraceMe.is_enabled()`` call per span when no
+profiler runs.  The name is the span's name alone (its args stay in the
+ring), and spans recorded retroactively (:meth:`Tracer.add_complete`) or
+as instants are not mirrored: they have no live extent to annotate.
 """
 
 from __future__ import annotations
@@ -38,6 +47,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 __all__ = ["Tracer", "Span", "NOOP_SPAN"]
 
@@ -57,6 +68,7 @@ class _NoopSpan:
     __slots__ = ()
     trace = None
     span_id = 0
+    duration_s = None
 
     def __enter__(self) -> "_NoopSpan":
         return self
@@ -80,7 +92,7 @@ class Span:
 
     __slots__ = ("_tracer", "name", "trace", "traces", "span_id",
                  "parent_id", "cat", "args", "_t0", "_tid", "_token",
-                 "_done")
+                 "_done", "_tm", "duration_s")
 
     def __init__(self, tracer: "Tracer", name: str, trace: Optional[str],
                  traces: Tuple[str, ...], parent_id: int, cat: str,
@@ -97,6 +109,12 @@ class Span:
         self._tid = threading.get_ident()
         self._token = None
         self._done = False
+        #: seconds from open to finish; None until finished
+        self.duration_s: Optional[float] = None
+        self._tm = None
+        if _TraceMe.is_enabled():
+            self._tm = _TraceMe(name)
+            self._tm.__enter__()
 
     def set(self, **args: Any) -> None:
         self.args.update(args)
@@ -118,10 +136,14 @@ class Span:
         if self._done:
             return
         self._done = True
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
+            self._tm = None
+        self.duration_s = time.perf_counter() - self._t0
         if args:
             self.args.update(args)
         self._tracer._append(
-            (self.name, "X", self._t0, time.perf_counter() - self._t0,
+            (self.name, "X", self._t0, self.duration_s,
              self.trace, self.traces, self._tid, self.span_id,
              self.parent_id, self.cat, self.args))
 

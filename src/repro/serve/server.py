@@ -43,6 +43,7 @@ import socket
 import subprocess
 import sys
 import threading
+import time
 from typing import Any, Dict, Optional, Tuple
 
 from .. import obs
@@ -51,6 +52,10 @@ from .graph_service import EdgeDelta, GraphService, Session
 from .policy import SchedulerPolicy, error_to_wire
 
 __all__ = ["GraphServer", "spawn_server", "publish_rmat", "main"]
+
+# from a request's resolution to its RESULT frame handed to the socket:
+# packing, the device-to-host copy in encoding, and the write
+_H_REPLY = obs.histogram("serve.reply_ms")
 
 
 class _Connection:
@@ -61,7 +66,8 @@ class _Connection:
         self.server = server
         self.sock = sock
         self.conn_id = conn_id
-        self.outbox: "queue.Queue[Optional[Tuple[int, int, Any]]]" = \
+        # (frame type, request id, payload, a RESULT's resolution time)
+        self.outbox: "queue.Queue[Optional[Tuple[int, int, Any, Any]]]" = \
             queue.Queue()
         self.closed = threading.Event()
         self.sessions: Dict[str, Session] = {}
@@ -85,18 +91,26 @@ class _Connection:
         return self.sessions[key]
 
     # -- outbound ------------------------------------------------------------
-    def send(self, ftype: int, req_id: int, payload: Any) -> None:
+    def send(self, ftype: int, req_id: int, payload: Any,
+             resolved_at: Optional[float] = None) -> None:
         if not self.closed.is_set():
-            self.outbox.put((ftype, req_id, payload))
+            self.outbox.put((ftype, req_id, payload, resolved_at))
 
     def _write_loop(self) -> None:
         while True:
             item = self.outbox.get()
             if item is None:
                 break
-            ftype, req_id, payload = item
+            ftype, req_id, payload, resolved_at = item
             try:
-                wire.send_frame(self.sock, ftype, req_id, payload)
+                if resolved_at is None:
+                    wire.send_frame(self.sock, ftype, req_id, payload)
+                else:
+                    with obs.TRACER.span("serve.reply.write",
+                                         conn=self.conn_id, cat="rpc"):
+                        wire.send_frame(self.sock, ftype, req_id, payload)
+                    _H_REPLY.observe(
+                        (time.perf_counter() - resolved_at) * 1e3)
             except (OSError, wire.WireError):
                 break
         self._teardown()
@@ -230,13 +244,15 @@ class _Connection:
 
     def _stream_result(self, req_id: int, p: Any) -> None:
         """Pending resolution -> RESULT frame (runs on the resolver)."""
-        if p.error is not None:
-            payload: Dict[str, Any] = {"error": error_to_wire(p.error)}
-        else:
-            payload = {"result": wire.pack_object(p.value)}
-        payload.update(cached=p.cached, fused=p.fused,
-                       queued_ms=p.queued_ms)
-        self.send(wire.FrameType.RESULT, req_id, payload)
+        with obs.TRACER.span("serve.reply.pack", trace=p.trace,
+                             conn=self.conn_id, cat="rpc"):
+            if p.error is not None:
+                payload: Dict[str, Any] = {"error": error_to_wire(p.error)}
+            else:
+                payload = {"result": wire.pack_object(p.value)}
+            payload.update(cached=p.cached, fused=p.fused,
+                           queued_ms=p.queued_ms)
+        self.send(wire.FrameType.RESULT, req_id, payload, p.completed_at)
 
     def _op_flush(self, req_id: int, msg: dict) -> dict:
         self.server.service.flush()

@@ -320,11 +320,18 @@ def test_frontier_fixpoint_emits_round_spans_and_metrics():
     names = [e["name"] for e in doc["traceEvents"] if e["ph"] == "X"]
     assert "engine.frontier_fixpoint" in names
     rounds = [e for e in doc["traceEvents"]
-              if e["name"] == "engine.frontier.round"]
+              if e["name"] in ("engine.frontier.push",
+                               "engine.frontier.pull")]
     assert rounds, "per-round spans missing from the trace"
     assert all("frontier" in e["args"] for e in rounds)
     # first round explores from the single source
     assert rounds[0]["args"]["frontier"] == 1
+    # each round's stats fetch nests inside it
+    syncs = [e for e in doc["traceEvents"]
+             if e["name"] == "engine.frontier.sync"]
+    ids = {e["args"]["span_id"] for e in rounds}
+    assert sum(e["args"].get("parent_id") in ids for e in syncs) == \
+        len(rounds)
 
 
 def test_pagerank_tol_iterations_observed_cold_vs_warm():
@@ -740,27 +747,31 @@ def test_slo_shed_tightens_admission():
 
 
 def test_profile_compile_execute_split_and_report():
+    """Compiles are counted from JAX's own compile events: a new program
+    counts once, and calling it again compiles nothing."""
+    import jax
+    import jax.numpy as jnp
     obs.reset()
-    g = small_graph()
-    A.pagerank(g, n_iter=3)
-    A.pagerank(g, n_iter=3)            # same signature: execute only
+    fresh = jax.jit(lambda x: x * 3 + 1)        # a program never compiled
+    x = jnp.arange(7)
+    fresh(x)
     snap = obs.dump_metrics()
-    prof = {k: v for k, v in snap.items()
-            if k.startswith("engine.profile.") and v["type"] == "histogram"}
-    assert prof, "profiled fixpoint must emit engine.profile.* histograms"
-    total = sum(v["count"] for v in prof.values())
-    assert total >= 2
-    # the second identical call must not land in a compile_ms bucket again:
-    # at most one compile observation per (backend, signature)
-    compiles = sum(v["count"] for k, v in prof.items()
-                   if k.endswith(".compile_ms"))
-    executes = sum(v["count"] for k, v in prof.items()
-                   if k.endswith(".execute_ms"))
-    assert executes >= 1
-    assert compiles <= total - 1
+    n = snap["engine.compiles"]["value"]
+    assert n >= 1
+    assert snap["engine.compile_ms"]["count"] == n
+    assert snap["engine.compile_ms"]["sum"] > 0
+    fresh(x)                                     # same program: no compile
+    A.pagerank(small_graph(), n_iter=3)
+    A.pagerank(small_graph(), n_iter=3)
+    n = obs.dump_metrics()["engine.compiles"]["value"]
+    A.pagerank(small_graph(), n_iter=3)
+    snap = obs.dump_metrics()
+    assert snap["engine.compiles"]["value"] == n
+    assert not any(k.endswith((".execute_ms", ".compile_ms"))
+                   and k.startswith("engine.profile.") for k in snap)
     rep = obs.profile_report()
     assert "engine profile" in rep
-    assert "execute_ms" in rep
+    assert "compile_ms" in rep and f"engine.compiles = {n}" in rep
 
 
 def test_profile_frontier_round_phases():
@@ -777,3 +788,147 @@ def test_profile_frontier_round_phases():
                 ("engine.profile.frontier.dense_ms",
                  "engine.profile.frontier.sparse_ms") if k in snap)
     assert timed == rounds, "every frontier round gets a timed phase"
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: spans mirrored as TraceMe events
+# ---------------------------------------------------------------------------
+
+
+def _host_events(trace_dir):
+    """(name, start ns, end ns) of every host event of a recorded trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_spans_mirror_onto_the_profiler_clock(tmp_path):
+    import jax
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("probe.outer", k=1):
+            with tr.span("probe.inner"):
+                time.sleep(0.002)
+        handed = tr.span("probe.handed")          # finished on another thread
+        t = threading.Thread(target=handed.finish)
+        t.start()
+        t.join()
+        now = time.perf_counter()
+        tr.add_complete("probe.retro", now - 0.01, now)
+        tr.instant("probe.instant")
+    finally:
+        jax.profiler.stop_trace()
+    evs = {n: (s, e) for n, s, e in _host_events(tmp_path)
+           if n.startswith("probe.")}
+    assert set(evs) == {"probe.outer", "probe.inner", "probe.handed"}
+    (os_, oe), (is_, ie) = evs["probe.outer"], evs["probe.inner"]
+    assert os_ <= is_ and ie <= oe and ie - is_ >= 2_000_000
+    # the ring keeps the same spans, with the durations the profiler saw
+    durs = {e["name"]: e["dur"] for e in tr.export_chrome_trace()[
+        "traceEvents"] if e["ph"] == "X"}
+    assert durs["probe.inner"] >= 2000.0
+
+
+def test_disabled_spans_open_no_traceme_while_profiling(tmp_path):
+    import jax
+    tr = Tracer(enabled=False)
+
+    def burn():
+        for _ in range(1000):
+            s = tr.span("probe.off")
+            s.finish()
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        burn()
+        gc.collect()
+        before = sys.getallocatedblocks()
+        burn()
+        gc.collect()
+        after = sys.getallocatedblocks()
+    finally:
+        jax.profiler.stop_trace()
+    assert after - before < 50
+    assert not [n for n, _, _ in _host_events(tmp_path)
+                if n == "probe.off"]
+
+
+# ---------------------------------------------------------------------------
+# frontier rounds: one span per direction, edge counters
+# ---------------------------------------------------------------------------
+
+
+def test_frontier_round_spans_end_after_their_fetch_and_count_edges():
+    from repro.core import engine
+    rng = np.random.default_rng(5)
+    src = rng.integers(0, 300, 3000).astype(np.int32)
+    dst = rng.integers(0, 300, 3000).astype(np.int32)
+    g = Graph.from_edges(src, dst)
+    ex = engine.get_exec(g.plan(), "frontier")
+    n, e = g.n_nodes, int(ex.n_edges)
+    init = np.full((2, n), np.iinfo(np.int32).max, np.int32)
+    init[0, 0] = init[1, 1] = 0
+    mask = np.zeros((n,), bool)
+    mask[:2] = True
+    names = ("engine.frontier.push_edges", "engine.frontier.pull_edges")
+    c0 = [obs.counter(c).value for c in names]
+    tid = obs.new_trace_id()
+    with obs.span("test.frontier_edges", trace=tid):
+        engine.frontier_fixpoint(ex, init, mask, weights=np.int32(1))
+    evs = obs.export_chrome_trace(trace=tid)["traceEvents"]
+    push = [x for x in evs if x["name"] == "engine.frontier.push"]
+    pull = [x for x in evs if x["name"] == "engine.frontier.pull"]
+    assert push and pull, "a BFS this dense switches direction"
+    by_id = {x["args"]["span_id"]: x for x in push + pull}
+    syncs = [x for x in evs if x["name"] == "engine.frontier.sync"
+             and x["args"].get("parent_id") in by_id]
+    assert len(syncs) == len(by_id)
+    for sy in syncs:
+        rnd = by_id[sy["args"]["parent_id"]]
+        assert rnd["ts"] <= sy["ts"]
+        assert sy["ts"] + sy["dur"] <= rnd["ts"] + rnd["dur"] + 1e-3
+    d_push = obs.counter(names[0]).value - c0[0]
+    d_pull = obs.counter(names[1]).value - c0[1]
+    assert d_push == sum(2 * x["args"]["edges"] for x in push)
+    assert d_pull == 2 * e * len(pull)
+
+
+# ---------------------------------------------------------------------------
+# plan building: one span per family, outermost builds observed once
+# ---------------------------------------------------------------------------
+
+
+def test_plan_builds_are_spanned_and_observed_once():
+    from repro.core import engine
+    h = obs.histogram("plan.build_ms", buckets=obs.LONG_BUCKETS_MS)
+    tid = obs.new_trace_id()
+    rng = np.random.default_rng(11)
+    with obs.span("test.plan_builds", trace=tid):
+        n0 = h.count
+        g = Graph.from_edges(rng.integers(0, 90, 700),
+                             rng.integers(0, 90, 700))
+        assert h.count == n0 + 1          # from_dense_edges nests: once
+        plan = g.plan()
+        assert h.count == n0 + 2
+        engine.get_exec(plan, "frontier")
+        assert h.count == n0 + 3          # csr_out, in_perm_out nest: once
+        engine.get_exec(plan, "frontier")
+        plan.csr_out()
+        assert h.count == n0 + 3          # cached: no build
+        plan.csr_in()
+        assert h.count == n0 + 4
+    names = [e["name"] for e in obs.export_chrome_trace(trace=tid)[
+        "traceEvents"] if e["name"].startswith("plan.build.")]
+    assert names.count("plan.build.graph") == 2
+    for want in ("plan.build.base", "plan.build.exec.frontier",
+                 "plan.build.csr_out", "plan.build.in_perm_out",
+                 "plan.build.csr_in"):
+        assert names.count(want) == 1, (want, names)

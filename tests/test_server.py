@@ -621,3 +621,26 @@ def test_health_slo_and_bundle_over_the_wire(served, tmp_path):
         assert client.profile_report().startswith("engine profile")
     finally:
         obs.reset()
+
+
+def test_reply_time_observed_once_per_reply(served_workers):
+    """``serve.reply_ms`` takes one observation per RESULT frame, and the
+    pack and write of each reply are spanned on the threads doing them."""
+    from repro import obs
+    _, client = served_workers
+    h = obs.histogram("serve.reply_ms")
+    n0 = h.count
+    sess = client.session("replies")
+    pend = [sess.submit({"op": "bfs", "graph": "g",
+                         "params": {"source": s}}) for s in range(3)]
+    for p in pend:
+        p.result()
+    deadline = time.time() + 10
+    while h.count < n0 + 3 and time.time() < deadline:
+        time.sleep(0.01)          # the writer observes after its send
+    assert h.count == n0 + 3
+    assert h.sum > 0
+    evs = obs.export_chrome_trace(trace=pend[0].trace)["traceEvents"]
+    assert [e["name"] for e in evs].count("serve.reply.pack") == 1
+    names = {e["name"] for e in obs.export_chrome_trace()["traceEvents"]}
+    assert "serve.reply.write" in names
