@@ -123,6 +123,10 @@ _C_RELAX = obs.counter("engine.frontier.relaxed_edges")
 _C_PUSH_EDGES = obs.counter("engine.frontier.push_edges")
 _C_PULL_EDGES = obs.counter("engine.frontier.pull_edges")
 _C_RETRACE = obs.counter("engine.frontier.retraces")
+# Pallas sum pulls/pushes, each one gather of the vertex vector into the
+# chunk buffer; counted when traced (a fixpoint body is traced once, so
+# this counts reduction sites, not iterations)
+_C_ONE_GATHER = obs.counter("engine.pallas.one_gather_pulls")
 # (rows, node bucket, edge budget, weighted, dtype) signatures already traced
 # by the bucketed-pow2 frontier steps: a new signature = one jit retrace
 _TRACED_SHAPES: set = set()
@@ -207,6 +211,15 @@ def _select_backend(plan, backend: Optional[str],
 # ---------------------------------------------------------------------------
 
 
+def _plain_float_sum(x: jax.Array, combine: str,
+                     edge_values: Optional[jax.Array] = None) -> bool:
+    """Whether a reduction can take a kernel's f32 sum path: an unweighted
+    sum of one float vector.  Anything else falls back to XLA, which keeps
+    min/max, integer dtypes and batched operands exact."""
+    return (combine == "sum" and edge_values is None and x.ndim == 1
+            and jnp.issubdtype(x.dtype, jnp.floating))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class XlaExec:
@@ -286,18 +299,20 @@ class XlaExec:
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class PallasExec(XlaExec):
-    """Sum reductions via the one-hot-matmul Pallas kernel.
+    """Sum pulls/pushes via the one-hot-matmul Pallas kernel.
 
     The chunk *structure* (which edge lands in which chunk/slot) is static
-    per graph and comes precomputed from the plan; each reduction only
-    scatters fresh values into the (C, L) chunk buffer on device.  min/max
-    and batched reductions fall back to the XLA primitives.
+    per graph and comes precomputed from the plan, as the vertex each slot
+    reads; each reduction only gathers the vertex vector straight into the
+    (C, L) chunk buffer on device: one gather per pull.  Weighted, min/max,
+    integer and batched reductions, and ``reduce_in``/``reduce_out`` of
+    caller-given edge values, fall back to the XLA primitives.
     """
 
-    p_src: jax.Array = None     # pull layout: (C, L) edge in each slot, pad E
+    p_vsrc: jax.Array = None    # pull layout: (C, L) vertex read, pad n
     p_lids: jax.Array = None    # (C, L) local ids, pad = 128
     p_blk: jax.Array = None     # (C,) owning output block
-    q_src: jax.Array = None     # push layout (over out_src)
+    q_vsrc: jax.Array = None    # push layout: slots over out_src, read out_dst
     q_lids: jax.Array = None
     q_blk: jax.Array = None
     nb_in: int = 0
@@ -306,8 +321,8 @@ class PallasExec(XlaExec):
 
     def tree_flatten(self):
         return ((self.in_src, self.in_dst, self.out_src, self.out_dst,
-                 self.p_src, self.p_lids, self.p_blk,
-                 self.q_src, self.q_lids, self.q_blk),
+                 self.p_vsrc, self.p_lids, self.p_blk,
+                 self.q_vsrc, self.q_lids, self.q_blk),
                 (self.n_nodes, self.n_edges, self.nb_in, self.nb_out,
                  self.interpret))
 
@@ -317,26 +332,26 @@ class PallasExec(XlaExec):
         return cls(n_nodes, n_edges, *leaves, nb_in=nb_in, nb_out=nb_out,
                    interpret=interpret)
 
-    def _chunked_sum(self, edge_vals, src, lids, blk, nb):
-        out = segment_sum_chunked(chunk_values(edge_vals, src), lids, blk, nb,
-                                  interpret=self.interpret)
+    def _chunked_sum(self, x, vsrc, lids, blk, nb):
+        _C_ONE_GATHER.inc()
+        # pads of ``vsrc`` index n_nodes, the zero chunk_values appends
+        out = segment_sum_chunked(chunk_values(x[: self.n_nodes], vsrc), lids,
+                                  blk, nb, interpret=self.interpret)
         return out.reshape(-1)[: self.n_nodes]
 
-    def reduce_in(self, edge_vals, combine="sum"):
-        # non-sum, batched, and integer reductions fall back: the f32 matmul
+    def pull(self, x, combine="sum", edge_values=None, edge_op="mul"):
+        # anything but an unweighted float sum falls back: the f32 matmul
         # path would change exactness/dtype, violating backend neutrality
-        if (combine != "sum" or edge_vals.ndim != 1
-                or not jnp.issubdtype(edge_vals.dtype, jnp.floating)):
-            return super().reduce_in(edge_vals, combine)
-        return self._chunked_sum(edge_vals, self.p_src, self.p_lids,
-                                 self.p_blk, self.nb_in)
+        if not _plain_float_sum(x, combine, edge_values):
+            return super().pull(x, combine, edge_values, edge_op)
+        return self._chunked_sum(x, self.p_vsrc, self.p_lids, self.p_blk,
+                                 self.nb_in)
 
-    def reduce_out(self, edge_vals, combine="sum"):
-        if (combine != "sum" or edge_vals.ndim != 1
-                or not jnp.issubdtype(edge_vals.dtype, jnp.floating)):
-            return super().reduce_out(edge_vals, combine)
-        return self._chunked_sum(edge_vals, self.q_src, self.q_lids,
-                                 self.q_blk, self.nb_out)
+    def push(self, x, combine="sum", edge_values=None, edge_op="mul"):
+        if not _plain_float_sum(x, combine, edge_values):
+            return super().push(x, combine, edge_values, edge_op)
+        return self._chunked_sum(x, self.q_vsrc, self.q_lids, self.q_blk,
+                                 self.nb_out)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -383,14 +398,12 @@ class BsrExec(XlaExec):
         return y.reshape(-1)[: self.n_nodes]
 
     def pull(self, x, combine="sum", edge_values=None, edge_op="mul"):
-        if (combine != "sum" or edge_values is not None or x.ndim != 1
-                or not jnp.issubdtype(x.dtype, jnp.floating)):
+        if not _plain_float_sum(x, combine, edge_values):
             return super().pull(x, combine, edge_values, edge_op)
         return self._spmv(self.tiles, self.rows, self.cols, x)
 
     def push(self, x, combine="sum", edge_values=None, edge_op="mul"):
-        if (combine != "sum" or edge_values is not None or x.ndim != 1
-                or not jnp.issubdtype(x.dtype, jnp.floating)):
+        if not _plain_float_sum(x, combine, edge_values):
             return super().push(x, combine, edge_values, edge_op)
         return self._spmv(self.tiles_t, self.rows_t, self.cols_t, x)
 
@@ -673,9 +686,10 @@ def get_exec(plan, backend: Optional[str] = None, *,
             ptr, idx, deg_pad = plan.csr_out()
             ex = FrontierExec(*base, ptr, idx, deg_pad, plan.in_perm_out())
         elif backend == "pallas":
-            p_src, p_lids, p_blk, nb_in, _ = plan.chunk_layout_in(chunk)
-            q_src, q_lids, q_blk, nb_out, _ = plan.chunk_layout_out(chunk)
-            ex = PallasExec(*base, p_src, p_lids, p_blk, q_src, q_lids, q_blk,
+            p_vsrc, p_lids, p_blk, nb_in, _ = plan.chunk_layout_in(chunk)
+            q_vsrc, q_lids, q_blk, nb_out, _ = plan.chunk_layout_out(chunk)
+            ex = PallasExec(*base, p_vsrc, p_lids, p_blk,
+                            q_vsrc, q_lids, q_blk,
                             nb_in=nb_in, nb_out=nb_out, interpret=interp)
         else:
             tiles, rows, cols, nb = plan.bsr(block)

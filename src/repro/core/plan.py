@@ -34,7 +34,9 @@ Lazily built and cached on first use:
     tri_triples(block) BSR tile triples for A.(A@A) triangle counting
     chunk_layout_in / chunk_layout_out
                        static chunk structure for the Pallas segment-sum
-                       backend (pull / push reduction order respectively)
+                       backend (pull / push reduction order respectively),
+                       as the slot -> vertex index a sum pull gathers
+                       through
     sharded(d)         per-shard arrays for the multi-device "sharded"
                        backend: contiguous vertex-range partition of both
                        CSR orders, halo/boundary index sets for the cut
@@ -501,19 +503,26 @@ class GraphPlan:
         return self._tri_triples[block]
 
     def chunk_layout_in(self, chunk: int = DEFAULT_CHUNK):
-        """Pallas chunk structure for per-destination (pull) reductions."""
+        """Pallas chunk structure for per-destination (pull) reductions:
+        ``(slot_vertex, local_ids, chunk_block, nb, C)``, where
+        ``slot_vertex`` is ``in_src[slot_entry]`` with pads at ``n_nodes``
+        (see :func:`_device_layout`)."""
         if chunk not in self._chunks_in:
             with building("chunk_layout_in"):
-                self._chunks_in[chunk] = _device_layout(chunk_layout(
-                    np.asarray(self.in_dst), self.n_nodes, chunk))
+                self._chunks_in[chunk] = _device_layout(
+                    chunk_layout(np.asarray(self.in_dst), self.n_nodes, chunk),
+                    np.asarray(self.in_src), self.n_nodes)
         return self._chunks_in[chunk]
 
     def chunk_layout_out(self, chunk: int = DEFAULT_CHUNK):
-        """Pallas chunk structure for per-source (push) reductions."""
+        """Pallas chunk structure for per-source (push) reductions; its
+        ``slot_vertex`` is ``out_dst[slot_entry]``."""
         if chunk not in self._chunks_out:
             with building("chunk_layout_out"):
-                self._chunks_out[chunk] = _device_layout(chunk_layout(
-                    np.asarray(self.out_src), self.n_nodes, chunk))
+                self._chunks_out[chunk] = _device_layout(
+                    chunk_layout(np.asarray(self.out_src), self.n_nodes,
+                                 chunk),
+                    np.asarray(self.out_dst), self.n_nodes)
         return self._chunks_out[chunk]
 
     def sharded(self, n_shards: int, axis: Optional[str] = None) -> ShardPlan:
@@ -685,7 +694,19 @@ def _host_in_perm_out(info) -> Optional[np.ndarray]:
     return np.searchsorted(ki, ko).astype(np.int32)
 
 
-def _device_layout(layout):
+def _device_layout(layout, edge_vertex: np.ndarray, n_nodes: int):
+    """Upload a chunk layout, its slot -> entry index composed with
+    ``edge_vertex`` into a slot -> vertex index.
+
+    ``slot_vertex`` is the vertex whose value each slot reads,
+    ``edge_vertex[slot_entry]``, so a pull gathers the vertex vector
+    straight into the chunk buffer, with no edge-order intermediate.  Pads
+    read ``n_nodes``, the zero that :func:`chunk_values` appends: a real
+    vertex there would put its value in a pad slot, and the kernel's zero
+    one-hot row turns an inf into NaN.
+    """
     slot_entry, local_ids, chunk_block, nb, total = layout
-    return (jnp.asarray(slot_entry), jnp.asarray(local_ids),
+    slot_vertex = np.append(edge_vertex.astype(np.int32),
+                            np.int32(n_nodes))[slot_entry]
+    return (jnp.asarray(slot_vertex), jnp.asarray(local_ids),
             jnp.asarray(chunk_block), nb, total)

@@ -192,6 +192,7 @@ def profile_report(snapshot: Optional[Dict[str, Any]] = None) -> str:
                           "engine.frontier.push_edges",
                           "engine.frontier.pull_edges",
                           "engine.frontier.retraces",
+                          "engine.pallas.one_gather_pulls",
                           "engine.exec_cache.hits",
                           "engine.exec_cache.misses")
               if n in snapshot]

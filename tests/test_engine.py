@@ -8,14 +8,18 @@ and functional updates must invalidate by construction.
 
 import inspect
 
+import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import algorithms as A
 from repro.core import engine
 from repro.core.graph import EdgeDelta, Graph
 from repro.data.rmat import rmat_edges
+from repro.kernels.segment_sum import (chunk_layout, chunk_values,
+                                       segment_sum_chunked)
 
 BACKENDS = ["xla", "pallas", "bsr", "frontier"]
 
@@ -438,3 +442,141 @@ def test_int_cumsum_matches_cumsum(m):
     x = np.random.default_rng(m).integers(0, 50, m).astype(np.int32)
     got = np.asarray(_int_cumsum(jnp.asarray(x)))
     assert got.dtype == np.int32 and np.array_equal(got, np.cumsum(x))
+
+
+# ---------------------------------------------------------------------------
+# Pallas sum pulls: one gather through the composed slot -> vertex index
+# ---------------------------------------------------------------------------
+
+
+def _chunked_graph():
+    """A graph whose chunk layout has uneven 128-blocks (the last one is
+    partial), pad slots, and more than one chunk in some block."""
+    g = rmat_graph(scale=9, edge_factor=8, seed=3)
+    ex = engine.get_exec(g.plan(), "pallas", interpret=True)
+    for vsrc, blk in ((ex.p_vsrc, ex.p_blk), (ex.q_vsrc, ex.q_blk)):
+        assert np.bincount(np.asarray(blk)).max() >= 2
+        assert (np.asarray(vsrc) == g.n_nodes).any()
+    assert g.n_nodes % 128 != 0
+    return g, ex
+
+
+def _two_gather(ex, x, direction):
+    """The reduction composed as two gathers, from the host chunk layout:
+    gather edge-order values, then gather those into the chunk buffer."""
+    if direction == "pull":
+        ev, seg, lids, blk, nb = (x[ex.in_src], ex.in_dst, ex.p_lids,
+                                  ex.p_blk, ex.nb_in)
+    else:
+        ev, seg, lids, blk, nb = (x[ex.out_dst], ex.out_src, ex.q_lids,
+                                  ex.q_blk, ex.nb_out)
+    slot_entry = jnp.asarray(chunk_layout(np.asarray(seg), ex.n_nodes)[0])
+    out = segment_sum_chunked(chunk_values(ev, slot_entry), lids, blk, nb,
+                              interpret=True)
+    return np.asarray(out.reshape(-1)[: ex.n_nodes])
+
+
+def _one_gather(ex, x, direction):
+    return np.asarray(ex.pull(x, "sum") if direction == "pull"
+                      else ex.push(x, "sum"))
+
+
+@pytest.mark.parametrize("direction", ["pull", "push"])
+def test_pallas_one_gather_matches_two_gather_bitwise(direction):
+    g, ex = _chunked_graph()
+    x = jnp.asarray(np.random.default_rng(7).normal(
+        size=g.n_nodes).astype(np.float32))
+    np.testing.assert_array_equal(_one_gather(ex, x, direction),
+                                  _two_gather(ex, x, direction))
+
+
+@pytest.mark.parametrize("direction", ["pull", "push"])
+def test_pallas_one_gather_pads_read_zero_with_inf(direction):
+    """An inf in x spoils only the output blocks of chunks that hold one
+    of its edges (the one-hot matmul multiplies every slot by 0 or 1): a
+    pad slot that read it would spoil every block with pads."""
+    g, ex = _chunked_graph()
+    if direction == "pull":
+        src, vsrc, blk = ex.in_src, ex.p_vsrc, ex.p_blk
+    else:
+        src, vsrc, blk = ex.out_dst, ex.q_vsrc, ex.q_blk
+    fan = np.bincount(np.asarray(src), minlength=g.n_nodes)
+    hot = int(np.flatnonzero(fan == fan[fan > 0].min())[0])
+    x = np.random.default_rng(8).random(g.n_nodes).astype(np.float32)
+    x[hot] = np.inf
+    x = jnp.asarray(x)
+    got = _one_gather(ex, x, direction)
+    np.testing.assert_array_equal(got, _two_gather(ex, x, direction))
+    pads = np.asarray(vsrc) == g.n_nodes
+    assert (np.asarray(chunk_values(x, vsrc))[pads] == 0.0).all()
+    blk = np.asarray(blk)
+    spoiled = np.unique(blk[(np.asarray(vsrc) == hot).any(axis=1)])
+    clean = np.setdiff1d(blk[pads.any(axis=1)], spoiled)
+    assert clean.size, "some block with pads must hold none of hot's edges"
+    block_of = np.arange(g.n_nodes) // 128
+    assert np.isfinite(got[np.isin(block_of, clean)]).all()
+    assert not np.isfinite(got[np.isin(block_of, spoiled)]).all()
+
+
+@pytest.mark.parametrize("direction", ["pull", "push"])
+def test_pallas_one_gather_vmapped_rows(direction):
+    g, ex = _chunked_graph()
+    xs = jnp.asarray(np.random.default_rng(9).random(
+        (3, g.n_nodes)).astype(np.float32))
+    fn = (lambda r: ex.pull(r, "sum")) if direction == "pull" else \
+        (lambda r: ex.push(r, "sum"))
+    batched = np.asarray(jax.vmap(fn)(xs))
+    for i in range(3):
+        np.testing.assert_array_equal(batched[i], np.asarray(fn(xs[i])))
+
+
+def _one_gather_count():
+    return obs.counter("engine.pallas.one_gather_pulls").value
+
+
+def test_pallas_edge_value_reductions_fall_back_to_xla():
+    """Weighted pulls and reductions of caller-given edge values take the
+    XLA segment reductions, bit for bit, and no Pallas pull is counted."""
+    g, ex = _chunked_graph()
+    xla = engine.get_exec(g.plan(), "xla")
+    x = jnp.asarray(np.random.default_rng(10).random(
+        g.n_nodes).astype(np.float32))
+    w = jnp.asarray(np.random.default_rng(11).random(
+        g.n_edges).astype(np.float32))
+    n0 = _one_gather_count()
+    for got, want in (
+            (ex.pull(x, "sum", edge_values=w),
+             xla.pull(x, "sum", edge_values=w)),
+            (ex.push(x, "sum", edge_values=w),
+             xla.push(x, "sum", edge_values=w)),
+            (ex.reduce_in(w), xla.reduce_in(w)),
+            (ex.reduce_out(w), xla.reduce_out(w))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert _one_gather_count() == n0
+
+
+def test_pallas_gather_counter_counts_traced_sites():
+    g, ex = _chunked_graph()
+    x = jnp.ones((g.n_nodes,), jnp.float32)
+    n0 = _one_gather_count()
+    ex.pull(x, "sum")
+    ex.push(x, "sum")
+    assert _one_gather_count() == n0 + 2
+    # non-sum, integer and batched operands fall back to XLA: no count
+    ex.pull(x, "max")
+    ex.pull(x.astype(jnp.int32), "sum")
+    ex.pull(jnp.ones((g.n_nodes, 2), jnp.float32), "sum")
+    assert _one_gather_count() == n0 + 2
+    # a jitted body counts once, when traced, however often it runs
+    f = jax.jit(lambda ex, v: ex.pull(v, "sum"))
+    f(ex, x)
+    f(ex, x + 1.0)
+    assert _one_gather_count() == n0 + 3
+
+
+def test_pallas_pagerank_takes_one_gather_path():
+    g = rmat_graph(scale=8, edge_factor=4, seed=12)
+    n0 = _one_gather_count()
+    A.pagerank(g, n_iter=3, backend="pallas", interpret=True)
+    assert _one_gather_count() - n0 >= 1
+    assert "engine.pallas.one_gather_pulls" in obs.profile_report()

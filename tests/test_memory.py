@@ -33,6 +33,7 @@ from repro.core import provenance as P
 from repro.core.graph import EdgeDelta, Graph
 from repro.core.plan import EVICTABLE_FAMILIES
 from repro.data.rmat import rmat_edges
+from repro.kernels.segment_sum import chunk_layout
 from repro.serve.graph_service import GraphService, RejectedError, Workspace
 from repro.serve.policy import AdmissionPolicy, MemoryPolicy, SchedulerPolicy
 
@@ -96,6 +97,46 @@ def test_plan_nbytes_per_family_and_transparent_evict():
     assert np.array_equal(before["bsr"], np.asarray(p.bsr()[0]))
     assert np.array_equal(before["und"],
                           np.asarray(p.undirected().out_edges()[0]))
+
+
+def _composed_slot_vertex(plan, seg, edge_vertex):
+    """slot -> vertex as a gather of the host layout's slot -> edge: pads
+    (edge E) read n."""
+    slot_entry = chunk_layout(np.asarray(seg), plan.n_nodes)[0]
+    return np.append(np.asarray(edge_vertex), plan.n_nodes)[slot_entry]
+
+
+def test_chunk_slot_vertex_index_charged_evicted_and_rebuilt():
+    g = rmat_graph()
+    p = g.plan()
+    lay_in, lay_out = p.chunk_layout_in(), p.chunk_layout_out()
+    vin, vout = np.asarray(lay_in[0]), np.asarray(lay_out[0])
+    np.testing.assert_array_equal(
+        vin, _composed_slot_vertex(p, p.in_dst, p.in_src))
+    np.testing.assert_array_equal(
+        vout, _composed_slot_vertex(p, p.out_src, p.out_dst))
+    assert (vin == p.n_nodes).any() and (vout == p.n_nodes).any()
+    # charged to "chunks", in place of a slot -> edge index
+    layout_bytes = sum(int(a.nbytes) for lay in (lay_in, lay_out)
+                       for a in (lay[0], lay[1], lay[2]))
+    assert p.nbytes_by_family()["chunks"] == layout_bytes
+    assert p.evict("chunks") == layout_bytes
+    assert p.nbytes_by_family()["chunks"] == 0
+    np.testing.assert_array_equal(np.asarray(p.chunk_layout_in()[0]), vin)
+    np.testing.assert_array_equal(np.asarray(p.chunk_layout_out()[0]), vout)
+    # a patched plan builds its own, over its own edge arrays
+    ids = np.asarray(g.node_ids)[:g.n_nodes]
+    child = g.apply_delta(EdgeDelta.inserts(ids[:5], ids[7:12]))
+    cp = child.plan()
+    assert cp._parent is p and cp.n_edges > p.n_edges
+    c_in, c_out = cp.chunk_layout_in(), cp.chunk_layout_out()
+    np.testing.assert_array_equal(
+        np.asarray(c_in[0]), _composed_slot_vertex(cp, cp.in_dst, cp.in_src))
+    np.testing.assert_array_equal(
+        np.asarray(c_out[0]),
+        _composed_slot_vertex(cp, cp.out_src, cp.out_dst))
+    assert cp.nbytes_by_family()["chunks"] == sum(
+        int(a.nbytes) for lay in (c_in, c_out) for a in lay[:3])
 
 
 def test_base_family_is_never_evictable():

@@ -20,6 +20,7 @@ around these compiles: entries for a described chip cannot be read back.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,18 +101,38 @@ def _pallas_exec(sds):
                              nb_in=NB22, nb_out=NB22, interpret=False)
 
 
+def _gathers(compiled):
+    """Result shapes of the gathers in a compiled program."""
+    return re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", compiled.as_text())
+
+
+# a Pallas sum pull gathers the vertex vector straight into the chunk
+# buffer: no edge-order f32[E22] intermediate, and no second gather
+ONE_CHUNK_GATHER = [f"f32[{C22},{DEFAULT_CHUNK}]"]
+
+
 def test_pallas_pagerank_fixpoint_scale22(sds):
     """The served PageRank program: ten pulls through the Pallas kernel."""
     run = engine._runner(A._pagerank_body, True)
     v = sds((N22,), jnp.float32)
-    _compile(run, _pallas_exec(sds), v, sds((), jnp.int32),
-             sds((), jnp.float32), v, sds((N22,), jnp.bool_))
+    compiled = _compile(run, _pallas_exec(sds), v, sds((), jnp.int32),
+                        sds((), jnp.float32), v, sds((N22,), jnp.bool_))
+    assert _gathers(compiled) == ONE_CHUNK_GATHER
 
 
 def test_pallas_batched_pull_scale22(sds):
     """A fused multi-source burst vmaps the Pallas pull over sources."""
-    _compile(lambda ex, x: jax.vmap(lambda r: ex.pull(r, "sum"))(x),
-             _pallas_exec(sds), sds((3, N22), jnp.float32))
+    compiled = _compile(
+        lambda ex, x: jax.vmap(lambda r: ex.pull(r, "sum"))(x),
+        _pallas_exec(sds), sds((3, N22), jnp.float32))
+    assert _gathers(compiled) == ONE_CHUNK_GATHER
+
+
+def test_pallas_push_scale22(sds):
+    """A Pallas push (HITS' hub step) gathers through the push layout."""
+    compiled = _compile(lambda ex, x: ex.push(x, "sum"), _pallas_exec(sds),
+                        sds((N22,), jnp.float32))
+    assert _gathers(compiled) == ONE_CHUNK_GATHER
 
 
 def test_frontier_round_scale22(sds):
